@@ -52,6 +52,8 @@ def _run_rows(name: str, mod, timeout_s: int) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (roofline, stencil_throughput, table1_subkernels,
                             table2_requirements, table3_model_validation)
     mods = [("table1", table1_subkernels), ("table2", table2_requirements),

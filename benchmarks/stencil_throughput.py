@@ -16,9 +16,9 @@ achieved HBM bandwidth), the radius-2 builtins (star13 / box125: streaming
 still ~2 x itemsize/point where the replicated path pays 6 x), a j-tiled
 run at a size where the untiled N x P slab exceeds the VMEM budget
 (previously a hard wall), a 2-device halo-exchange ``shard_map`` run
-(forced host-platform devices, in a subprocess so this process keeps its
-single-device view), and an 8-device 2x2x2 process-grid pair timing the
-serialized vs compute/communication-overlap schedules.
+(forced host-platform devices, in a subprocess pinned to the CPU so this
+process keeps its single-device view), and an 8-device 2x2x2 process-grid
+pair timing the serialized vs compute/communication-overlap schedules.
 
 Besides the ``name,us_per_call,derived`` text rows, every measurement is
 recorded as a dict and the whole run is dumped to ``BENCH_stencil.json``
@@ -664,8 +664,14 @@ def _sharded_row() -> str:
 
 def _subprocess_rows(code: str, fallback_name: str, n_dev: int) -> List[str]:
     """Run ``code`` under ``n_dev`` forced host devices and parse every
-    ``name,usec,derived`` stdout line into text rows + JSON records."""
+    ``name,usec,derived`` stdout line into text rows + JSON records.
+
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``): this process
+    already holds any accelerator, and these rows are host-device rows by
+    design.  A child that fails, or prints a line that is not a row, raises
+    -- the benchmark run then exits non-zero."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
@@ -674,22 +680,18 @@ def _subprocess_rows(code: str, fallback_name: str, n_dev: int) -> List[str]:
                          capture_output=True, text=True, timeout=600, env=env)
     if out.returncode != 0:
         err_lines = out.stderr.strip().splitlines() or ["(no stderr)"]
-        _RECORDS.append({"name": fallback_name, "us_per_call": None,
-                         "ok": False, "error": err_lines[-1][:200]})
-        return [f"{fallback_name},nan,FAILED: {err_lines[-1][:120]}"]
+        raise RuntimeError(f"{fallback_name}: {n_dev}-device child exited "
+                           f"{out.returncode}: {err_lines[-1][:200]}")
     rows = []
     for line in out.stdout.strip().splitlines() or ["(no stdout)"]:
         parts = line.split(",", 2)
-        if len(parts) == 3:
-            name, usec, derived = parts
-            _RECORDS.append({"name": name, "us_per_call": float(usec),
-                             "ok": "ok=True" in derived, "derived": derived})
-            rows.append(line)
-        else:
-            _RECORDS.append({"name": fallback_name, "us_per_call": None,
-                             "ok": False,
-                             "error": f"unparseable row: {line[:200]}"})
-            rows.append(f"{fallback_name},nan,unparseable: {line[:120]}")
+        if len(parts) != 3:
+            raise RuntimeError(f"{fallback_name}: unparseable row from the "
+                               f"{n_dev}-device child: {line[:200]}")
+        name, usec, derived = parts
+        _RECORDS.append({"name": name, "us_per_call": float(usec),
+                         "ok": "ok=True" in derived, "derived": derived})
+        rows.append(line)
     return rows
 
 
@@ -729,5 +731,7 @@ def _sharded_grid_rows() -> List[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     quick = "--quick" in sys.argv[1:]
     print("\n".join(run_quick() if quick else run()))

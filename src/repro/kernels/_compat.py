@@ -1,27 +1,18 @@
-"""JAX-version aliases and the parametrized legacy-shim machinery.
+"""The seed-era per-stencil entry points, built by one parametrized factory.
 
-Two kinds of compatibility live here:
-
-* **JAX-version aliases** -- ``pltpu.TPUCompilerParams`` was renamed
-  ``pltpu.CompilerParams``, and ``jax.experimental.shard_map`` graduated to
-  ``jax.shard_map``, in newer JAX; kernels import the aliases from here so
-  they run on both.
-
-* **Legacy per-stencil entry points** -- the seed-era
-  ``stencil{3,7,27}`` / ``stencil{3,7,27}_ref`` wrappers, built once by the
-  ``_make_entry`` / ``_make_ref`` factories below (one parametrized body
-  instead of three copy-pasted shim packages).  The historical import paths
-  (``repro.kernels.stencil3`` etc., ``repro.kernels.stencil_engine.compat``,
-  ``repro.kernels._stencil_common``) all re-export from this module.  The
-  one deliberate behavior change (inherited from the engine migration):
-  ``interpret`` defaults to ``None`` ("interpret only when no compiled
-  Pallas backend exists"), so the same call site runs compiled on TPU and
-  interpreted on CPU/GPU/CI.
+The ``stencil{3,7,27}`` / ``stencil{3,7,27}_ref`` wrappers are built once by
+the ``_make_entry`` / ``_make_ref`` factories below (one parametrized body
+instead of three copy-pasted shim packages).  The historical import paths
+(``repro.kernels.stencil3`` etc., ``repro.kernels.stencil_engine.compat``,
+``repro.kernels._stencil_common``) all re-export from this module.  The one
+deliberate behavior change (inherited from the engine migration):
+``interpret`` defaults to ``None`` ("interpret only when no compiled Pallas
+backend exists"), so the same call site runs compiled on TPU and interpreted
+on CPU/GPU/CI.
 
 The wrappers import the engine lazily (inside the traced body) so this
-module stays import-cycle-free: ``stencil_engine.sharded`` imports
-``shard_map`` from here while ``stencil_engine.compat`` imports the entry
-points, and both directions must work whichever module loads first.
+module stays import-cycle-free: ``stencil_engine.compat`` imports the entry
+points from here, and that must work whichever module loads first.
 """
 
 from __future__ import annotations
@@ -29,14 +20,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 # One row per legacy entry point: registry name -> (name of the static

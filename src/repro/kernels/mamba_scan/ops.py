@@ -9,7 +9,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 from .kernel import mamba_scan_kernel
 
 
@@ -40,7 +39,7 @@ def mamba_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, dim), ld),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((dim, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, bm, c, d)

@@ -19,17 +19,22 @@ minimizing the modeled time per output point:
   (8 shifts + 19 flops) therefore models ~4x cheaper than the naive
   schedule (54 + 53), which shifts the DMA/VPU crossover -- the paper's
   Table-4 point that the synthesized schedule changes which resource binds.
-* VMEM residency: the staged tiles (input dtype) + the extended working
-  strip and its tap accumulator (accumulation dtype) -- plus, on the
-  streaming path, the ``bi + ri * sweeps``-plane rotating scratch window --
-  must fit the budget: the paper's Table-2 "registers required vs registers
-  available" constraint in VMEM terms.
+* VMEM residency: what Mosaic allocates for one grid step must fit the
+  budget -- every staged input view and the output block twice (Pallas
+  double-buffers them), the streaming path's ``bi + ri * sweeps``-plane
+  rotating scratch window, and ``peak_live + 2`` working strips in the
+  accumulation dtype (the assembled input strip, the plan's live partial
+  sums, the masked result).  The budget is half the scoped-VMEM limit the
+  kernels compile with (:data:`~.common.VMEM_LIMIT_BYTES`): the paper's
+  Table-2 "registers required vs registers available" constraint in VMEM
+  terms.
 
 Feasible blocks divide M (and N when j-tiled -- Pallas grid constraint) and
 satisfy ``bi >= ri * s`` / ``bj >= rj * s`` (the carried window / +-1-block
-halo must cover the fused-sweep halo depth).  j-tiling engages only when no
-full-N block fits the budget.  Ties prefer sublane multiples (8), as the old
-heuristic did.
+halo must cover the fused-sweep halo depth); a j-tile is a whole number of
+sublane tiles (:func:`sublane_rows`) wherever N allows one.  j-tiling
+engages only when no full-N block fits the budget.  Ties prefer sublane
+multiples (8), as the old heuristic did.
 
 :func:`autotune_engine` is the top-level entry: it races the streaming and
 replicated rooflines per shape and returns ``(path, block_i, block_j)`` --
@@ -143,6 +148,25 @@ def _plan_apps(plan) -> int:
     return 1
 
 
+# Peak live values of the factored stencil27 schedule: the residency
+# model's stand-in when a legacy caller passes no plan.
+LEGACY_PEAK_LIVE = 6
+
+
+def _plan_live(plan) -> int:
+    """Simultaneously live SSA values of the plan (:func:`~.plan.peak_live`);
+    :data:`LEGACY_PEAK_LIVE` without one."""
+    if plan is not None:
+        return plan.peak_live
+    return LEGACY_PEAK_LIVE
+
+
+def sublane_rows(itemsize: int) -> int:
+    """Rows of one TPU sublane tile (8 x 32-bit; packed dtypes pack more):
+    the granule a j-tile's second-to-last block dim must be a multiple of."""
+    return 8 * max(1, 4 // itemsize)
+
+
 def _plan_var_weights(plan) -> int:
     """Coefficient planes staged per input view: ``n_weights`` for a
     variable-coefficient plan (its weights are domain-shaped fields that
@@ -237,19 +261,26 @@ def _fits(bi: int, bj: Optional[int], n: int, p: int, itemsize: int,
           sweeps: int, acc_itemsize: int, vmem_budget: int,
           path: str = "replicate",
           radius: Tuple[int, int, int] = (1, 1, 1),
-          var_weights: int = 0, apps: int = 1) -> bool:
+          var_weights: int = 0, apps: int = 1,
+          live: int = LEGACY_PEAK_LIVE) -> bool:
+    """Does one grid step's VMEM residency fit ``vmem_budget``?  Counts
+    what Mosaic allocates: the staged input views and the output block,
+    each double-buffered; the streaming scratch window; and ``live + 2``
+    working strips (``live`` = the plan's peak live values) in the
+    accumulation dtype."""
     wj, ej, views = _geometry(bi, bj, n, sweeps, path, radius, apps)
     hi = radius[0] * sweeps * apps
-    io_tiles = (views + 1) * bi * wj * p * itemsize
+    io_tiles = 2 * (views + 1) * bi * wj * p * itemsize
     scratch = (bi + hi) * ej * p * itemsize if path == "stream" else 0
-    working = 2 * (bi + 2 * hi) * ej * p * acc_itemsize
+    strip = (bi + 2 * hi) * ej * p * acc_itemsize
+    working = (live + 2) * strip
     if var_weights:
-        # staged coefficient views + co-rotating scratch + assembled strip,
-        # all in the accumulation dtype
-        io_tiles += views * var_weights * bi * wj * p * acc_itemsize
+        # staged coefficient views (double-buffered) + co-rotating scratch +
+        # assembled strip, all in the accumulation dtype
+        io_tiles += 2 * views * var_weights * bi * wj * p * acc_itemsize
         if path == "stream":
             scratch += var_weights * (bi + hi) * ej * p * acc_itemsize
-        working += var_weights * (bi + 2 * hi) * ej * p * acc_itemsize
+        working += var_weights * strip
     return io_tiles + scratch + working <= vmem_budget
 
 
@@ -275,6 +306,7 @@ def autotune_blocks(m: int, n: int, p: int, itemsize: int,
     shifts, flops = _plan_ops(plan, taps)
     var_w = _plan_var_weights(plan)
     apps = _plan_apps(plan)
+    live = _plan_live(plan)
     rad = _radius3(radius, plan)
     min_bi = max(1, rad[0] * sweeps * apps)
     min_bj = max(1, rad[1] * sweeps * apps)
@@ -289,17 +321,19 @@ def autotune_blocks(m: int, n: int, p: int, itemsize: int,
     if block_j is None:
         feasible = [bi for bi in cands_i
                     if _fits(bi, None, n, p, itemsize, sweeps, acc_itemsize,
-                             vmem_budget, path, rad, var_w, apps)]
+                             vmem_budget, path, rad, var_w, apps, live)]
         if feasible:
             return min(feasible, key=lambda bi: key(bi, None)), None
         if not allow_j_tiling:      # nothing fits: smallest legal block
             return cands_i[0], None
         cands_j = [bj for bj in _divisors(n) if min_bj <= bj < n] or [n]
+        granule = sublane_rows(itemsize)
+        cands_j = [bj for bj in cands_j if bj % granule == 0] or cands_j
     else:
         cands_j = [block_j]
     pairs = [(bi, bj) for bi in cands_i for bj in cands_j
              if _fits(bi, bj, n, p, itemsize, sweeps, acc_itemsize,
-                      vmem_budget, path, rad, var_w, apps)]
+                      vmem_budget, path, rad, var_w, apps, live)]
     if pairs:
         return min(pairs, key=lambda bb: key(*bb))
     return cands_i[0], cands_j[0]   # nothing fits: smallest legal tile
@@ -341,7 +375,8 @@ def autotune_engine(m: int, n: int, p: int, itemsize: int,
                                  vmem_budget=vmem_budget, block_j=block_j,
                                  path=cand, radius=rad)
         feasible = _fits(bi, bj, n, p, itemsize, sweeps, acc_itemsize,
-                         vmem_budget, cand, rad, var_w, apps)
+                         vmem_budget, cand, rad, var_w, apps,
+                         _plan_live(plan))
         t = _step_time(bi, bj, n, p, itemsize, sweeps, shifts, flops, cand,
                        rad, var_w, apps)
         # infeasible blockings only ever win when nothing fits anywhere;
@@ -390,17 +425,18 @@ def pick_block_rows(rows: int, p: int, itemsize: int,
 # ---------------------------------------------------------------------------
 
 def _fits_wavefront(bi: int, n: int, p: int, itemsize: int, sweeps: int,
-                    acc_itemsize: int, vmem_budget: int, ha: int) -> bool:
+                    acc_itemsize: int, vmem_budget: int, ha: int,
+                    live: int = LEGACY_PEAK_LIVE) -> bool:
     """VMEM residency of the wavefront pipeline at block ``bi``: the staged
-    input view + output block, ``sweeps`` rotating stage windows of
-    ``bi + ha`` planes (stage 1 input dtype, the rest accumulation dtype),
-    and one working strip + accumulator per concurrently-live stage compute
-    (stages run sequentially within a step, so two strips bound the live
-    set)."""
-    io = 2 * bi * n * p * itemsize
+    input view + output block (each double-buffered), ``sweeps`` rotating
+    stage windows of ``bi + ha`` planes (stage 1 input dtype, the rest
+    accumulation dtype), and the ``live + 2`` working strips of one stage
+    compute (stages run sequentially within a step, so one stage's strips
+    bound the live set)."""
+    io = 2 * 2 * bi * n * p * itemsize
     scratch = ((bi + ha) * n * p * itemsize
                + (sweeps - 1) * (bi + ha) * n * p * acc_itemsize)
-    working = 2 * (bi + 2 * ha) * n * p * acc_itemsize
+    working = (live + 2) * (bi + 2 * ha) * n * p * acc_itemsize
     return io + scratch + working <= vmem_budget
 
 
@@ -438,7 +474,7 @@ def wavefront_block_i(m: int, n: int, p: int, itemsize: int, sweeps: int,
 
     feasible = [bi for bi in cands
                 if _fits_wavefront(bi, n, p, itemsize, sweeps, acc_itemsize,
-                                   vmem_budget, ha)]
+                                   vmem_budget, ha, plan.peak_live)]
     if feasible:
         return min(feasible, key=key)
     return cands[0]
@@ -580,7 +616,8 @@ def autotune_sweeps(m: int, n: int, p: int, itemsize: int, sweeps: int,
             bi = wavefront_block_i(m_wf, n, p, itemsize, sweeps, plan,
                                    acc_itemsize, vmem_budget)
             feasible = _fits_wavefront(bi, n, p, itemsize, sweeps,
-                                       acc_itemsize, vmem_budget, ha)
+                                       acc_itemsize, vmem_budget, ha,
+                                       plan.peak_live)
             read_f = m_wf / m
             bpp = (read_f + 1.0) * itemsize / sweeps
             tpp = _wavefront_step_time(bi, n, p, itemsize, sweeps, shifts,
@@ -594,7 +631,8 @@ def autotune_sweeps(m: int, n: int, p: int, itemsize: int, sweeps: int,
                 acc_itemsize=acc_itemsize, vmem_budget=vmem_budget,
                 block_j=block_j, path=path)
             feasible = _fits(bi, bj, n, p, itemsize, s_eff, acc_itemsize,
-                             vmem_budget, rpath, rad, var_w, apps)
+                             vmem_budget, rpath, rad, var_w, apps,
+                             plan.peak_live)
             bpp = bytes_per_point(rpath, itemsize, bj is not None, s_eff,
                                   rad, spec.coef, spec.n_weights)
             tpp = _step_time(bi, bj, n, p, itemsize, s_eff, shifts, flops,

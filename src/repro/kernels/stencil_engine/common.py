@@ -3,9 +3,9 @@
 Engine-wide constants and small helpers live here so the cost model, the
 block pickers, and the benchmarks agree on one source of truth:
 
-* :data:`DEFAULT_VMEM_BUDGET` -- the single VMEM residency budget every
-  block/tile chooser defaults to (previously ``8 << 20`` in
-  ``autotune_blocks`` and a stray ``4 << 20`` in ``pick_block_rows``).
+* :data:`VMEM_LIMIT_BYTES` -- the scoped-VMEM limit every engine kernel
+  compiles with, and :data:`DEFAULT_VMEM_BUDGET`, the residency budget
+  every block/tile chooser defaults to, derived from it.
 * :func:`divisors` -- sorted divisors of an int (block-size candidates).
 
 The rest are the original halo/tiling utilities the MXU banded-matmul
@@ -24,11 +24,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# One documented VMEM residency budget (bytes) for every engine block/tile
-# chooser: staged IO tiles + working strips + streaming scratch must fit
-# inside it.  ~half a TPU core's VMEM, leaving headroom for Pallas's own
-# double-buffering of the staged operands.
-DEFAULT_VMEM_BUDGET = 8 << 20
+# The scoped-VMEM limit (bytes) every engine pallas_call is compiled with
+# (``CompilerParams(vmem_limit_bytes=...)``), in place of Mosaic's 16 MiB
+# default, which refused the default 512^3 f32 path.  A pinned or fallback
+# geometry the model calls infeasible (the 512x512-plane wavefront) still
+# compiles under it.
+VMEM_LIMIT_BYTES = 48 << 20
+
+# The block/tile choosers' residency budget, derived from that one limit:
+# the model (``autotune._fits``) itemizes the double-buffered blocks, the
+# scratch windows and the working strips; the other half is headroom for
+# the compiler scratch it does not itemize.
+DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES // 2
 
 
 def divisors(x: int) -> List[int]:

@@ -26,11 +26,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .autotune import (PATH_KINDS, autotune_blocks, autotune_engine,
                        pick_block_rows)
+from .common import VMEM_LIMIT_BYTES
 from .kernel import (KernelFault, acc_dtype_for, stencil1d_kernel,
                      stencil3d_kernel, stencil3d_stream_kernel,
                      stencil3d_strip_kernel, stencil3d_wavefront_kernel)
 from .plan import StencilPlan, compile_plan
 from .spec import StencilSpec, get_stencil
+
+
+# Every engine pallas_call compiles under this one scoped-VMEM limit; the
+# block choosers' residency budget is derived from the same number
+# (:data:`~.common.DEFAULT_VMEM_BUDGET`).
+_TPU_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _periodic_axes(spec: StencilSpec):
@@ -47,7 +54,13 @@ def default_interpret() -> bool:
     everywhere.  The kernel bodies are Mosaic-TPU-shaped (``pltpu.VMEM``
     scratch windows carried across a sequential grid), which the GPU
     (Triton / Mosaic-GPU) lowerings do not provide, so GPU hosts stay on
-    the interpreter too."""
+    the interpreter too.
+
+    This is also a silent fallback: on a host whose TPU failed to
+    initialise, JAX's default backend is the CPU and every kernel then runs
+    interpreted there.  Code that must run on the chip checks for itself --
+    ``chip_smoke.py`` asserts the platform and that each compiled program
+    holds a ``tpu_custom_call``."""
     return jax.default_backend() != "tpu"
 
 
@@ -171,6 +184,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
             out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
+            compiler_params=_TPU_PARAMS,
         )(a4, geom, wf)
 
     nbj = n // bj
@@ -215,6 +229,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=_TPU_PARAMS,
     )(*([a4] * (2 * rj + 1)), geom, *w_args)
 
 
@@ -306,6 +321,7 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
             out_specs=pl.BlockSpec(block, lambda bb, i: (bb, i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
             interpret=interpret,
+            compiler_params=_TPU_PARAMS,
         )(*([a4] * (2 * ri + 1)), geom, *w_args)
 
     nbj = n // bj
@@ -337,6 +353,7 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
         out_specs=pl.BlockSpec(block, lambda bb, i, j: (bb, i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
         interpret=interpret,
+        compiler_params=_TPU_PARAMS,
     )(*([a4] * n_views), geom, *w_args)
 
 
@@ -399,6 +416,7 @@ def call_3d_wavefront(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=_TPU_PARAMS,
     )(a4, geom, wf)
 
 
@@ -439,6 +457,7 @@ def call_3d_strip(a4: jax.Array, wf: jax.Array, geom: jax.Array,
                                lambda bb: (bb, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, rows - 2 * h, n, p), a4.dtype),
         interpret=interpret,
+        compiler_params=_TPU_PARAMS,
     )(a4, geom, wf)
 
 
@@ -457,6 +476,7 @@ def _call_1d(a2: jax.Array, wf: jax.Array, plan: StencilPlan, block_rows: int,
         out_specs=pl.BlockSpec((block_rows, p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(a2.shape, a2.dtype),
         interpret=interpret,
+        compiler_params=_TPU_PARAMS,
     )(a2, wf)
 
 

@@ -35,8 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .._compat import shard_map
-
 from ...sharding.planner import (StencilGridPlan, StencilShardPlan,
                                  stencil_grid_sharding,
                                  stencil_halo_sharding)
@@ -132,8 +130,9 @@ def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
         return out[:, h:h + m_loc]
 
     w_spec = part if var else P(None)
-    fn = jax.jit(shard_map(local_fn, mesh=mesh, in_specs=(part, w_spec),
-                           out_specs=part, check_rep=False))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                               in_specs=(part, w_spec), out_specs=part,
+                               check_vma=False))
     _SHARDED_CACHE[key] = fn
     while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
         _SHARDED_CACHE.popitem(last=False)
@@ -282,8 +281,9 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
 
     local_fn = local_overlap if overlap == "on" else local_serial
     w_spec = part if var else P(None)
-    fn = jax.jit(shard_map(local_fn, mesh=mesh, in_specs=(part, w_spec),
-                           out_specs=part, check_rep=False))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                               in_specs=(part, w_spec), out_specs=part,
+                               check_vma=False))
     _SHARDED_CACHE[key] = fn
     while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
         _SHARDED_CACHE.popitem(last=False)

@@ -126,7 +126,6 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ArchConfig
                                if a in mesh.axis_names]))
     if (mesh is not None and "model" in mesh.axis_names
             and (b * s) % dp_size == 0 and (b * s) >= dp_size):
-        from jax.experimental.shard_map import shard_map
         tp = mesh.shape["model"]
         dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
@@ -148,11 +147,11 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ArchConfig
             aux = jax.lax.pmean(jax.lax.pmean(aux, "model"), dp)
             return out, aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dp, None), P(None, None), wi_s, wg_s, wo_s),
             out_specs=(P(dp, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(xf, p["router"]["w"], p["wi"], p["wg"], p["wo"])
     else:
         out, aux = _dispatch_compute_combine(

@@ -77,7 +77,6 @@ def make_train_step(model: Model, opt: Optimizer, lr_fn: Callable,
         return step
 
     # Compressed-DP path: explicit collectives via shard_map.
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def sharded_step(state, batch):
@@ -95,13 +94,13 @@ def make_train_step(model: Model, opt: Optimizer, lr_fn: Callable,
         state_spec = jax.tree.map(lambda _: P(), state)
         state_spec["ef"] = jax.tree.map(lambda _: P(), state["ef"])
         batch_spec = jax.tree.map(lambda _: P(data_axis), batch)
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(state_spec, batch_spec),
-                         out_specs=(state_spec,
-                                    jax.tree.map(lambda _: P(),
-                                                 {"loss": 0, "gnorm": 0,
-                                                  "lr": 0})),
-                         check_rep=False)(state, batch)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(state_spec, batch_spec),
+                             out_specs=(state_spec,
+                                        jax.tree.map(lambda _: P(),
+                                                     {"loss": 0, "gnorm": 0,
+                                                      "lr": 0})),
+                             check_vma=False)(state, batch)
 
     return sharded_step
 

@@ -19,7 +19,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any
@@ -69,10 +68,10 @@ def pipeline_forward(stage_fn: Callable, stage_params: Params, x: jax.Array,
         return jax.lax.psum(outs, axis)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(spec_p, P()),
-                    out_specs=P(),
-                    check_rep=False)(stage_params, xm)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(spec_p, P()),
+                        out_specs=P(),
+                        check_vma=False)(stage_params, xm)
     return out.reshape(b, *x.shape[1:])
 
 

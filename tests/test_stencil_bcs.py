@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import (BC, dirichlet, get_stencil, spec_from_mask,
                            stencil_apply, stencil_ref, stencil_sharded)
 from repro.kernels.stencil_engine.spec import as_boundary, bc_labels
@@ -103,7 +105,7 @@ def test_bc_bit_exact_vs_np_pad_oracle(name, block_i, bc, sweeps, path):
     reference across radius {1, 2} x path {stream, replicate} x sweeps
     {1, 3} -- and so does the engine's own jnp reference."""
     spec = get_stencil(name).with_bc(bc)
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_data((12, 12, 16))
         w = _int_weights(spec)
         want = np_pad_oracle(a, w, spec, sweeps=sweeps)
@@ -122,7 +124,7 @@ def test_bc_jtiled_bit_exact(name, bc):
     wrapped index maps instead of in-shift fill)."""
     spec = get_stencil(name).with_bc(bc)
     bi = 4 if spec.radius[0] == 1 else 6
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_data((12, 12, 16))
         w = _int_weights(spec)
         want = np_pad_oracle(a, w, spec)
@@ -138,7 +140,7 @@ def test_bc_mixed_per_axis_and_per_side():
     and an asymmetric ad-hoc mask (cse plan) under periodic BCs."""
     mix = ("periodic", ("neumann", "dirichlet"), "clamp")
     spec = get_stencil("stencil27").with_bc(mix)
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_data((8, 12, 16))
         w = _int_weights(spec)
         want = np_pad_oracle(a, w, spec, sweeps=2)

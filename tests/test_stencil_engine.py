@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import (compile_plan, get_stencil, list_stencils,
                            spec_from_mask, stencil_apply, stencil_ref,
                            stencil3_ref, stencil7_ref, stencil27_ref)
@@ -81,7 +83,7 @@ def test_volumetric_parity_sizes_dtypes(name, wshape, shape, bi, dtype):
 def test_f64_bit_for_bit_parity():
     """In f64 the kernel and the refs agree exactly (same tap order, same
     arithmetic) -- the engine's reference path."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(RNG.standard_normal((8, 10, 16)), jnp.float64)
         a2 = jnp.asarray(RNG.standard_normal((6, 32)), jnp.float64)
         w27 = jnp.asarray(RNG.uniform(0.1, 1.0, (2, 2, 2)), jnp.float64)
@@ -235,7 +237,7 @@ def test_plan_kinds_agree_and_match_ref():
 def test_factored_f64_bit_identical_to_ref():
     """Acceptance: stencil27 factored, f64, bit-identical to stencil_ref --
     blocked kernel vs full-array oracle, fused sweeps included."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(RNG.standard_normal((8, 10, 16)), jnp.float64)
         w = jnp.asarray(RNG.uniform(0.1, 1.0, (2, 2, 2)), jnp.float64)
         for sweeps in (1, 2):
@@ -307,8 +309,9 @@ def test_autotune_blocks_engages_j_tiling_past_vmem_wall():
     bi, bj = autotune_blocks(8, 288, 1024, 4, plan=plan)
     assert bj is not None and 288 % bj == 0 and 8 % bi == 0
     from repro.kernels.stencil_engine.autotune import _fits
-    assert _fits(bi, bj, 288, 1024, 4, 1, 4, 8 * 1024 * 1024)
-    assert not _fits(1, None, 288, 1024, 4, 1, 4, 8 * 1024 * 1024)
+    from repro.kernels.stencil_engine.common import DEFAULT_VMEM_BUDGET
+    assert _fits(bi, bj, 288, 1024, 4, 1, 4, DEFAULT_VMEM_BUDGET)
+    assert not _fits(1, None, 288, 1024, 4, 1, 4, DEFAULT_VMEM_BUDGET)
     # the plan-aware model charges the factored schedule ~4x less VPU work
     direct = compile_plan("stencil27", "direct")
     from repro.kernels.stencil_engine.autotune import _step_time

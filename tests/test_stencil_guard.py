@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import (GuardPolicy, guard_bytes_per_point,
                            last_guard_report, stencil_apply, stencil_ref,
                            stencil_sharded, stencil_sweep_driver)
@@ -95,7 +97,7 @@ def test_guard_off_is_byte_identical_and_never_checks():
     """The default dispatches straight to the historical jitted program:
     same bytes out as calling it directly, and the guard's check machinery
     never runs."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         before = guard_mod.CHECK_RUNS[0]
@@ -119,7 +121,7 @@ def test_guarded_clean_run_matches_off(name):
     """A clean guarded call is byte-identical to the unguarded program (the
     guard only *observes*), passes its checks, and reports final == start
     with no demotions -- across BC x ordering (no false positives)."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(_nw(name))
         off = stencil_apply(a, w, name)
@@ -137,7 +139,7 @@ def test_guarded_clean_run_matches_off(name):
 def test_run_guard_checks_no_false_positives(name, s):
     """The detectors stay silent on honest outputs, sampled and full, and
     the sampled strip oracle agrees with the full reference."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((14, 8, 32))
         w = _int_weights(_nw(name))
         spec = get_stencil(name)
@@ -164,7 +166,7 @@ def test_run_guard_checks_no_false_positives(name, s):
 def test_nan_window_caught_by_nan_screen_retry_recovers():
     """A one-shot NaN store: the nan check fails attempt 0, the same-rung
     retry runs clean -- no demotion, bit-exact result."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         with inject(NaNWindow(seed=7, plane=5)) as (inj,):
@@ -182,7 +184,7 @@ def test_nan_window_caught_by_nan_screen_retry_recovers():
 def test_bitflip_plane_caught_by_invariant():
     """An exponent-bit flip is huge but *finite*: it sails through the NaN
     screen and the weight-sum invariant trips."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         with inject(BitFlipPlane(seed=3, plane=6)) as (inj,):
@@ -201,7 +203,7 @@ def test_nan_scratch_kernel_fault_demotes_off_stream():
     """A NaN poisoned inside the stream kernel's VMEM rotating window (the
     static ``_fault`` hook): the screen catches it on the fused rung, the
     retry re-fires, and the ladder recovers on a lower rung, bit-exact."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         with inject(NaNScratchWindow(seed=1, plane=2, fires=3)) as (inj,):
@@ -220,7 +222,7 @@ def test_nan_scratch_kernel_fault_demotes_off_stream():
 def test_raising_candidate_demotes_and_blacklists():
     """A candidate that raises at run time: retried once, demoted, and the
     dead rung blacklisted in the autotuner so future auto races skip it."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         with inject(RaisingCandidate(rungs=("fused", "chained"))) as (inj,):
@@ -243,7 +245,7 @@ def test_raising_candidate_demotes_and_blacklists():
 def test_ladder_exhaustion_raises_guard_error():
     """When every rung (the oracle included) dies, the guard refuses to
     return unverified data."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((8, 8, 32))
         w = _int_weights(4)
         with inject(RaisingCandidate(rungs=LADDER)):
@@ -254,7 +256,7 @@ def test_ladder_exhaustion_raises_guard_error():
 def test_corrupt_halo_unsharded_caught():
     """The single-device analogue of a bad exchange: corrupted edge planes
     trip the invariant, and the retry recovers bit-exactly."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         with inject(CorruptHalo(seed=9, mode="garbage",
@@ -273,7 +275,7 @@ def test_corrupt_halo_unsharded_caught():
 # ---------------------------------------------------------------------------
 
 def test_guarded_driver_clean_wavefront():
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((16, 8, 32))
         w = _int_weights(4)
         off = stencil_sweep_driver(a, w, "stencil7", sweeps=3,
@@ -289,7 +291,7 @@ def test_guarded_driver_clean_wavefront():
 def test_guarded_driver_wavefront_demotes_to_fused():
     """A persistent fault on the wavefront rung (fires through the retry)
     walks the driver down to the fused rung, bit-exact."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((16, 8, 32))
         w = _int_weights(4)
         with inject(NaNWindow(seed=2, plane=7, rungs=("wavefront",),
@@ -309,7 +311,7 @@ def test_guarded_driver_wavefront_demotes_to_fused():
 def test_guarded_sharded_single_device():
     """The sharded entry point's guard path (1-device mesh): clean run,
     sharded-entry report, bit-exact vs the oracle."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((16, 8, 32))
         w = _int_weights(4)
         mesh = jax.make_mesh((1,), ("data",))
@@ -332,7 +334,7 @@ def test_sharded_corrupt_halo_2dev_subprocess():
     from repro.kernels import stencil_ref, stencil_sharded, last_guard_report
     from repro.kernels.stencil_engine import CorruptHalo, inject
     assert jax.device_count() == 2
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(5)
         a = jnp.asarray(rng.integers(-4, 5, (16, 8, 32)).astype(np.float64))
         w = jnp.asarray(rng.integers(-3, 4, 4).astype(np.float64))
@@ -367,7 +369,7 @@ def test_sharded_corrupt_halo_2dev_subprocess():
 # ---------------------------------------------------------------------------
 
 def test_guard_report_describe_schema():
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(4)
         stencil_apply(a, w, "stencil7", guard="invariant")
@@ -471,3 +473,21 @@ def test_bench_timeout_env_parsing(monkeypatch):
     assert run._timeout_s() == 0    # negative disables, never crashes
     monkeypatch.delenv("BENCH_TIMEOUT_S")
     assert run._timeout_s() == run.DEFAULT_TIMEOUT_S
+
+
+def test_bench_children_pinned_to_cpu_and_failures_raise(monkeypatch):
+    """A forced-host-device child runs on the CPU whatever platform the
+    parent asks for (the parent may hold the chip), and a child that fails
+    raises -- the benchmark run exits non-zero -- instead of leaving a
+    FAILED row behind an exit code of 0."""
+    st = _load_module(("benchmarks", "stencil_throughput.py"),
+                      "bench_children_test")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    rows = st._subprocess_rows(
+        "import jax; print(f'probe,1.0,{jax.devices()[0].platform} "
+        "n={jax.device_count()} ok=True')", "probe", n_dev=2)
+    assert rows == ["probe,1.0,cpu n=2 ok=True"]
+    with pytest.raises(RuntimeError, match="exited 3"):
+        st._subprocess_rows("import sys; sys.exit(3)", "probe", n_dev=2)
+    with pytest.raises(RuntimeError, match="unparseable"):
+        st._subprocess_rows("print('not a row')", "probe", n_dev=2)

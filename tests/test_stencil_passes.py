@@ -7,6 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import compile_plan, get_stencil, spec_from_mask
 from repro.kernels.stencil_engine.plan import (PASS_PRESETS, build_direct,
                                                cse, mirror_factor,
@@ -146,7 +148,7 @@ def test_ordered_plans_execute_identically():
     through the executor."""
     from repro.kernels import stencil_ref
     rng = np.random.default_rng(3)
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(rng.integers(-4, 5, (8, 10, 16)), jnp.float64)
         for name in ("stencil27", "star13", "box125"):
             spec = get_stencil(name)

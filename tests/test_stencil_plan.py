@@ -14,6 +14,8 @@ try:
 except ImportError:  # optional dev dep -- property tests skip, rest runs
     from _hypothesis_stub import given, settings, st  # noqa: F401
 
+from _x64 import x64
+
 from repro.kernels import (compile_plan, spec_from_mask, stencil_apply,
                            stencil_ref)
 from repro.kernels.stencil_engine.plan import mirror_symmetric
@@ -62,7 +64,7 @@ def check_plans_agree(seed: int, sweeps: int, block_j, symmetric: bool):
 
     # f64 + integer-valued data: every sum is exact, so reassociated plans
     # (and any blocking) must agree bit-for-bit.
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(rng.integers(-4, 5, shape), jnp.float64)
         w = jnp.asarray(rng.integers(1, 4, spec.n_weights), jnp.float64)
         outs = [np.asarray(stencil_apply(a, w, spec, block_i=3,

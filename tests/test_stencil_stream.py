@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import (autotune_engine, bytes_per_point, compile_plan,
                            get_stencil, spec_from_mask, stencil_apply,
                            stencil_ref)
@@ -82,7 +84,7 @@ def test_stream_f64_bit_identical_acceptance():
     """Acceptance: on the f64 reference configurations the streamed output
     is bit-identical to the replicated path and to stencil_ref -- fused
     sweeps and j-tiling included."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(RNG.standard_normal((8, 10, 16)), jnp.float64)
         w = jnp.asarray(RNG.uniform(0.1, 1.0, (2, 2, 2)), jnp.float64)
         for sweeps in (1, 2):
@@ -239,7 +241,7 @@ def test_radius2_stream_f64_bit_identical_acceptance():
     streamed path, the replicated path, and stencil_ref are bit-identical
     across fused sweeps and j-tiling; on float f64 data the two compiled
     programs agree to per-op contraction rounding (<= ~1 ulp)."""
-    with jax.experimental.enable_x64():
+    with x64():
         for name in ("star13", "box125"):
             spec = get_stencil(name)
             a = jnp.asarray(RNG.integers(-4, 5, (8, 10, 16)), jnp.float64)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import (SWEEP_MODES, autotune_sweeps, compile_plan,
                            get_stencil, stencil_apply, stencil_ref,
                            stencil_sweep_driver, stencil_wavefront)
@@ -47,7 +49,7 @@ SWEEP_SPECS = [
 def test_sweep_composition_bit_exact(name, nw, s):
     """s chained calls == one fused sweeps=s call == the wavefront driver
     == the oracle, bit-exact, across BC x radius x s."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(nw)
         chained = a
@@ -64,7 +66,7 @@ def test_sweep_composition_bit_exact(name, nw, s):
 def test_sweep_composition_across_paths():
     """The chained oracle is path-invariant, and the wavefront matches it
     whichever path produced it (stream vs replicate)."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(8)
         wave = stencil_wavefront(a, w, "stencil27", sweeps=3)
@@ -79,7 +81,7 @@ def test_sweep_composition_across_paths():
 
 @pytest.mark.parametrize("mode", ["auto", "fused", "wavefront", "chained"])
 def test_driver_modes_agree(mode):
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(8)
         ref = stencil_ref(a, w, "stencil27", sweeps=4)
@@ -88,7 +90,7 @@ def test_driver_modes_agree(mode):
 
 
 def test_driver_batched_and_block_pins():
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((2, 12, 8, 32))
         w = _int_weights(8)
         ref = stencil_ref(a, w, "stencil27", sweeps=2)
@@ -100,7 +102,7 @@ def test_driver_batched_and_block_pins():
 def test_redblack_kernel_matches_oracle():
     """Red-black Gauss-Seidel: masked half-sweeps in the kernel == the
     NumPy-oracle checkerboard, for 3-D and 1-D specs, all modes."""
-    with jax.experimental.enable_x64():
+    with x64():
         a = _int_field((12, 8, 32))
         w = _int_weights(8)
         ref = stencil_ref(a, w, "stencil27_redblack", sweeps=2)
@@ -158,7 +160,7 @@ def test_autotune_sweeps_race():
 
 
 def test_wavefront_input_validation():
-    with jax.experimental.enable_x64():
+    with x64():
         a1 = _int_field((6, 32))
         with pytest.raises(ValueError, match="volumetric"):
             stencil_wavefront(a1, _int_weights(2), "stencil3", sweeps=2)
@@ -180,7 +182,7 @@ def test_sharded_deep_halo_2dev_subprocess():
     import jax, numpy as np, jax.numpy as jnp
     from repro.kernels import stencil_apply, stencil_sharded
     assert jax.device_count() == 2
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(5)
         a = jnp.asarray(rng.integers(-4, 5, (16, 8, 32)).astype(np.float64))
         w8 = jnp.asarray(rng.integers(-3, 4, 8).astype(np.float64))

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _x64 import x64
+
 from repro.kernels import get_stencil, stencil_apply, stencil_ref
 from repro.kernels.stencil_engine.autotune import bytes_per_point
 
@@ -62,7 +64,7 @@ def test_var27_matches_independent_oracle(sweeps):
     """Non-circular: kernel AND engine ref against a hand-rolled loop."""
     spec = get_stencil("stencil27").with_coef("var")
     shape = (5, 6, 8)
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints(shape))
         w = jnp.asarray(_wints((spec.n_weights,) + shape))
         want = _oracle_var(a, w, spec, sweeps)
@@ -79,7 +81,7 @@ def test_var27_matches_independent_oracle(sweeps):
 def test_var_paths_sweeps_bitexact_vs_ref(name, path, sweeps, block_j):
     spec = get_stencil(name).with_coef("var")
     shape = (8, 12, 16)
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints(shape))
         w = jnp.asarray(_wints((spec.n_weights,) + shape))
         got = stencil_apply(a, w, spec, block_i=4, block_j=block_j,
@@ -93,7 +95,7 @@ def test_var_broadcast_weights_equal_materialized():
     fully materialized to (nw, M, N, P)."""
     spec = get_stencil("stencil27").with_coef("var")
     m, n, p = 8, 10, 16
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints((m, n, p)))
         wb = jnp.asarray(_wints((spec.n_weights, 1, 1, p)))
         wfull = jnp.broadcast_to(wb, (spec.n_weights, m, n, p))
@@ -108,7 +110,7 @@ def test_var_broadcast_weights_equal_materialized():
 def test_var_radius2_star13(path):
     spec = get_stencil("star13").with_coef("var")
     shape = (10, 12, 16)
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints(shape))
         w = jnp.asarray(_wints((spec.n_weights,) + shape))
         for bj in (None, 4):
@@ -125,7 +127,7 @@ def test_var_boundary_conditions(bc, path):
     over = dirichlet(2.0) if bc == "dirichlet" else bc
     spec = get_stencil("stencil27").with_coef("var").with_bc(over)
     shape = (8, 10, 16)
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints(shape))
         w = jnp.asarray(_wints((spec.n_weights,) + shape))
         got = stencil_apply(a, w, spec, block_i=4, sweeps=2, path=path)
@@ -135,7 +137,7 @@ def test_var_boundary_conditions(bc, path):
 
 def test_var_1d_stencil3():
     spec = get_stencil("stencil3").with_coef("var")
-    with jax.experimental.enable_x64():
+    with x64():
         a = jnp.asarray(_ints((6, 32)))
         w = jnp.asarray(_wints((spec.n_weights, 32)))
         got = stencil_apply(a, w, spec, block_i=3)
