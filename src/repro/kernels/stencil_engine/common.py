@@ -7,6 +7,9 @@ block pickers, and the benchmarks agree on one source of truth:
   compiles with, and :data:`DEFAULT_VMEM_BUDGET`, the residency budget
   every block/tile chooser defaults to, derived from it.
 * :func:`divisors` -- sorted divisors of an int (block-size candidates).
+* The profiler span names (``SPAN_*``) at the engine's host-path
+  boundaries and the fixed ``name=`` of each kernel (``KERNEL_*``), which
+  the device trace shows; :func:`annotate` attaches a span's arguments.
 
 The rest are the original halo/tiling utilities the MXU banded-matmul
 kernel still imports (``shifted_planes``, ``interior_mask``,
@@ -36,6 +39,44 @@ VMEM_LIMIT_BYTES = 48 << 20
 # scratch windows and the working strips; the other half is headroom for
 # the compiler scratch it does not itemize.
 DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES // 2
+
+# Profiler spans (``jax.profiler.TraceAnnotation``) at the boundaries of the
+# engine's host path.  One entry span wraps each public entry call; entries
+# called from inside another (the sweep driver's fused and chained modes
+# call ``stencil_apply``) nest, and the outermost one is the call.
+SPAN_APPLY = "stencil.apply"
+SPAN_SWEEP_DRIVER = "stencil.sweep_driver"
+SPAN_SHARDED = "stencil.sharded"
+ENTRY_SPANS = (SPAN_APPLY, SPAN_SWEEP_DRIVER, SPAN_SHARDED)
+# The host-side choice of an entry (spec, BC and guard resolution, the
+# plan, the sweep mode and blocks), up to the executor call; its arguments
+# carry the decision where the host makes one.
+SPAN_SELECT = "stencil.select"
+# The call into the jitted executor until it returns: JAX's dispatch and the
+# runtime's enqueue.
+SPAN_LAUNCH = "stencil.launch"
+# Inside the jitted bodies: it runs only while JAX traces them, so each span
+# is one retrace (and compile or compile-cache load), with the decision made
+# at trace time as its arguments.
+SPAN_TRACE = "stencil.trace"
+SPANS = ENTRY_SPANS + (SPAN_SELECT, SPAN_LAUNCH, SPAN_TRACE)
+
+# The fixed ``name=`` of each ``pallas_call`` site in :mod:`.ops`: the HLO
+# instruction, and so the device trace's op, carries it.
+KERNEL_STREAM = "stencil_stream"
+KERNEL_STREAM_JTILED = "stencil_stream_jtiled"
+KERNEL_REPLICATE = "stencil_replicate"
+KERNEL_REPLICATE_JTILED = "stencil_replicate_jtiled"
+KERNEL_WAVEFRONT = "stencil_wavefront"
+KERNEL_STRIP = "stencil_strip"
+KERNEL_ROWS = "stencil_rows"
+
+
+def annotate(span: jax.profiler.TraceAnnotation, **args) -> None:
+    """Attach ``args`` (those not ``None``) to the open ``span``, only while
+    a profiler records: with none active this costs one check."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        span.set_metadata(**{k: v for k, v in args.items() if v is not None})
 
 
 def divisors(x: int) -> List[int]:

@@ -23,10 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
 from .autotune import (PATH_KINDS, autotune_blocks, autotune_engine,
                        pick_block_rows)
-from .common import VMEM_LIMIT_BYTES
+from .common import (KERNEL_REPLICATE, KERNEL_REPLICATE_JTILED, KERNEL_ROWS,
+                     KERNEL_STREAM, KERNEL_STREAM_JTILED, KERNEL_STRIP,
+                     KERNEL_WAVEFRONT, SPAN_APPLY, SPAN_LAUNCH, SPAN_SELECT,
+                     SPAN_TRACE, VMEM_LIMIT_BYTES, annotate)
 from .kernel import (KernelFault, acc_dtype_for, stencil1d_kernel,
                      stencil3d_kernel, stencil3d_stream_kernel,
                      stencil3d_strip_kernel, stencil3d_wavefront_kernel)
@@ -185,6 +189,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
             scratch_shapes=scratch,
             interpret=interpret,
             compiler_params=_TPU_PARAMS,
+            name=KERNEL_STREAM,
         )(a4, geom, wf)
 
     nbj = n // bj
@@ -230,6 +235,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
+        name=KERNEL_STREAM_JTILED,
     )(*([a4] * (2 * rj + 1)), geom, *w_args)
 
 
@@ -322,6 +328,7 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
             out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
             interpret=interpret,
             compiler_params=_TPU_PARAMS,
+            name=KERNEL_REPLICATE,
         )(*([a4] * (2 * ri + 1)), geom, *w_args)
 
     nbj = n // bj
@@ -354,6 +361,7 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
         out_shape=jax.ShapeDtypeStruct(a4.shape, a4.dtype),
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
+        name=KERNEL_REPLICATE_JTILED,
     )(*([a4] * n_views), geom, *w_args)
 
 
@@ -417,6 +425,7 @@ def call_3d_wavefront(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
+        name=KERNEL_WAVEFRONT,
     )(a4, geom, wf)
 
 
@@ -458,6 +467,7 @@ def call_3d_strip(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, rows - 2 * h, n, p), a4.dtype),
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
+        name=KERNEL_STRIP,
     )(a4, geom, wf)
 
 
@@ -477,6 +487,7 @@ def _call_1d(a2: jax.Array, wf: jax.Array, plan: StencilPlan, block_rows: int,
         out_shape=jax.ShapeDtypeStruct(a2.shape, a2.dtype),
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
+        name=KERNEL_ROWS,
     )(a2, wf)
 
 
@@ -492,50 +503,60 @@ def _stencil_apply_impl(a: jax.Array, w: jax.Array,
     ``_fault`` is the static in-kernel fault-injection descriptor
     (:class:`~.kernel.KernelFault`; tests only) -- ``None``, the default,
     traces the byte-identical historical program."""
-    if sweeps < 1:
-        raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    if path not in PATH_KINDS:
-        raise ValueError(f"unknown path {path!r}; expected one of "
-                         f"{PATH_KINDS}")
-    spec = get_stencil(stencil)
-    if spec.guard != "off":
-        # The guard never reaches the traced program: strip it so guarded
-        # and unguarded calls share plan and jit caches.
-        spec = spec.with_guard("off")
-    if bc is not None:
-        spec = spec.with_bc(bc)
-    cplan = compile_plan(spec, plan)
-    acc = acc_dtype_for(a.dtype)
-    var = spec.coef == "var"
-    interp = resolve_interpret(interpret)
+    with TraceAnnotation(SPAN_TRACE) as span:
+        if sweeps < 1:
+            raise ValueError(f"sweeps must be >= 1, got {sweeps}")
+        if path not in PATH_KINDS:
+            raise ValueError(f"unknown path {path!r}; expected one of "
+                             f"{PATH_KINDS}")
+        spec = get_stencil(stencil)
+        if spec.guard != "off":
+            # The guard never reaches the traced program: strip it so
+            # guarded and unguarded calls share plan and jit caches.
+            spec = spec.with_guard("off")
+        if bc is not None:
+            spec = spec.with_bc(bc)
+        cplan = compile_plan(spec, plan)
+        acc = acc_dtype_for(a.dtype)
+        var = spec.coef == "var"
+        interp = resolve_interpret(interpret)
 
-    if spec.ndim == 1:
-        if a.ndim < 2:
-            raise ValueError(f"{spec.name}: need (..., rows, P), got {a.shape}")
-        wf = spec.canon_weights(w, a.shape[-1:] if var else None).astype(acc)
-        rows = int(np.prod(a.shape[:-1]))
-        a2 = a.reshape(rows, a.shape[-1])
-        br = block_i or pick_block_rows(rows, a.shape[-1], a.dtype.itemsize)
-        return _call_1d(a2, wf, cplan, br, sweeps, interp).reshape(a.shape)
+        if spec.ndim == 1:
+            if a.ndim < 2:
+                raise ValueError(f"{spec.name}: need (..., rows, P), got "
+                                 f"{a.shape}")
+            wf = spec.canon_weights(w, a.shape[-1:] if var else None
+                                    ).astype(acc)
+            rows = int(np.prod(a.shape[:-1]))
+            a2 = a.reshape(rows, a.shape[-1])
+            br = block_i or pick_block_rows(rows, a.shape[-1],
+                                            a.dtype.itemsize)
+            annotate(span, path="rows", block_i=br, plan=cplan.kind,
+                     sweeps=sweeps)
+            return _call_1d(a2, wf, cplan, br, sweeps, interp
+                            ).reshape(a.shape)
 
-    if a.ndim < 3:
-        raise ValueError(f"{spec.name}: need (..., M, N, P), got {a.shape}")
-    m, n, p = a.shape[-3:]
-    wf = spec.canon_weights(w, (m, n, p) if var else None).astype(acc)
-    batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
-    a4 = a.reshape(batch, m, n, p)
-    bi, bj, rpath = block_i, block_j, path
-    if bi is None:
-        rpath, bi, bj_auto = autotune_engine(m, n, p, a.dtype.itemsize,
-                                             sweeps=sweeps, plan=cplan,
-                                             block_j=bj, path=path)
-        bj = bj if bj is not None else bj_auto
-    elif rpath == "auto":
-        rpath = "stream"            # pinned blocks: stream is strictly
-    geom = jnp.array([0, m], jnp.int32)  # fewer HBM bytes at equal blocks
-    out = call_3d(a4, wf, geom, cplan, bi, bj, sweeps, interp, rpath,
-                  fault=_fault)
-    return out.reshape(a.shape)
+        if a.ndim < 3:
+            raise ValueError(f"{spec.name}: need (..., M, N, P), got "
+                             f"{a.shape}")
+        m, n, p = a.shape[-3:]
+        wf = spec.canon_weights(w, (m, n, p) if var else None).astype(acc)
+        batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
+        a4 = a.reshape(batch, m, n, p)
+        bi, bj, rpath = block_i, block_j, path
+        if bi is None:
+            rpath, bi, bj_auto = autotune_engine(m, n, p, a.dtype.itemsize,
+                                                 sweeps=sweeps, plan=cplan,
+                                                 block_j=bj, path=path)
+            bj = bj if bj is not None else bj_auto
+        elif rpath == "auto":
+            rpath = "stream"        # pinned blocks: stream is strictly
+        geom = jnp.array([0, m], jnp.int32)  # fewer HBM bytes at equal blocks
+        annotate(span, path=rpath, block_i=bi, block_j=bj, plan=cplan.kind,
+                 sweeps=sweeps)
+        out = call_3d(a4, wf, geom, cplan, bi, bj, sweeps, interp, rpath,
+                      fault=_fault)
+        return out.reshape(a.shape)
 
 
 stencil_apply_jit = jax.jit(
@@ -600,20 +621,25 @@ def stencil_apply(a: jax.Array, w: jax.Array,
       fused -> chained -> stream -> replicate -> oracle, returning the
       first verified result (see ``last_guard_report()``).
     """
-    spec = get_stencil(stencil)
-    policy_src = spec.guard if guard is None else guard
-    if policy_src is None or policy_src == "off":
-        return stencil_apply_jit(a, w, stencil, block_i=block_i,
-                                 block_j=block_j, plan=plan, sweeps=sweeps,
-                                 path=path, bc=bc, interpret=interpret)
-    from .guard import as_guard, guarded_apply
-    policy = as_guard(policy_src)
-    if policy is None:              # e.g. an explicit guard="off" string
-        return stencil_apply_jit(a, w, stencil, block_i=block_i,
-                                 block_j=block_j, plan=plan, sweeps=sweeps,
-                                 path=path, bc=bc, interpret=interpret)
-    if bc is not None:
-        spec = spec.with_bc(bc)
-    return guarded_apply(a, w, spec, policy, block_i=block_i,
-                         block_j=block_j, plan=plan, sweeps=sweeps,
-                         path=path, interpret=interpret)
+    with TraceAnnotation(SPAN_APPLY):
+        with TraceAnnotation(SPAN_SELECT):
+            # The path and blocks are chosen while the executor traces
+            # (``stencil.trace``); the host resolves the spec and guard.
+            spec = get_stencil(stencil)
+            policy_src = spec.guard if guard is None else guard
+            policy = None
+            if policy_src is not None and policy_src != "off":
+                from .guard import as_guard
+                policy = as_guard(policy_src)  # None: e.g. guard="off"
+        if policy is None:
+            with TraceAnnotation(SPAN_LAUNCH):
+                return stencil_apply_jit(a, w, stencil, block_i=block_i,
+                                         block_j=block_j, plan=plan,
+                                         sweeps=sweeps, path=path, bc=bc,
+                                         interpret=interpret)
+        from .guard import guarded_apply
+        if bc is not None:
+            spec = spec.with_bc(bc)
+        return guarded_apply(a, w, spec, policy, block_i=block_i,
+                             block_j=block_j, plan=plan, sweeps=sweeps,
+                             path=path, interpret=interpret)

@@ -27,12 +27,14 @@ indefinitely).
 
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
-from typing import Optional, Union
+from typing import Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ...sharding.planner import (StencilGridPlan, StencilShardPlan,
@@ -40,6 +42,8 @@ from ...sharding.planner import (StencilGridPlan, StencilShardPlan,
                                  stencil_halo_sharding)
 from .autotune import (PATH_KINDS, autotune_engine, autotune_sweeps,
                        exchange_bytes_per_point, wavefront_block_i)
+from .common import (SPAN_LAUNCH, SPAN_SELECT, SPAN_SHARDED, SPAN_TRACE,
+                     annotate)
 from .kernel import acc_dtype_for
 from .ops import (call_3d, call_3d_strip, call_3d_wavefront,
                   resolve_interpret, stencil_apply)
@@ -73,6 +77,16 @@ def _mesh_key(mesh: Mesh) -> tuple:
     names."""
     return (tuple((d.platform, int(d.id)) for d in mesh.devices.flat),
             tuple(mesh.devices.shape), tuple(mesh.axis_names))
+
+
+def _traced(body: Callable, **args) -> Callable:
+    """The shard_map body ``body`` inside the ``stencil.trace`` span, which
+    records the trace-time decision ``args``: it runs once per trace."""
+    def local_fn(a_loc: jax.Array, wf_: jax.Array) -> jax.Array:
+        with TraceAnnotation(SPAN_TRACE) as span:
+            annotate(span, **args)
+            return body(a_loc, wf_)
+    return local_fn
 
 
 def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
@@ -130,9 +144,11 @@ def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
         return out[:, h:h + m_loc]
 
     w_spec = part if var else P(None)
-    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
-                               in_specs=(part, w_spec), out_specs=part,
-                               check_vma=False))
+    fn = jax.jit(jax.shard_map(
+        _traced(local_fn, mode=mode, path=path, block_i=bi, block_j=bj,
+                plan=cplan.kind, sweeps=sweeps),
+        mesh=mesh, in_specs=(part, w_spec), out_specs=part,
+        check_vma=False))
     _SHARDED_CACHE[key] = fn
     while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
         _SHARDED_CACHE.popitem(last=False)
@@ -281,26 +297,29 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
 
     local_fn = local_overlap if overlap == "on" else local_serial
     w_spec = part if var else P(None)
-    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
-                               in_specs=(part, w_spec), out_specs=part,
-                               check_vma=False))
+    fn = jax.jit(jax.shard_map(
+        _traced(local_fn, mode=mode, path=path, block_i=bi, block_j=bj,
+                plan=cplan.kind, sweeps=sweeps, overlap=overlap),
+        mesh=mesh, in_specs=(part, w_spec), out_specs=part,
+        check_vma=False))
     _SHARDED_CACHE[key] = fn
     while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
         _SHARDED_CACHE.popitem(last=False)
     return fn
 
 
-def _grid_dispatch(a: jax.Array, w: jax.Array, spec: StencilSpec,
-                   cplan: StencilPlan, mesh: Mesh, gaxes,
-                   grid_plan: Optional[StencilGridPlan],
-                   block_i: Optional[int], block_j: Optional[int],
-                   plan_kind: str, sweeps: int, path: str, mode: str,
-                   overlap: str, interpret: bool) -> jax.Array:
-    """Plan, tune, and run :func:`stencil_sharded`'s process-grid route
-    (multi-axis ``axes`` and/or ``overlap="on"``); split out to keep the
-    entry point readable.  ``gaxes`` is the resolved (ai, aj, ak) triple,
-    ``grid_plan`` a caller-supplied :class:`StencilGridPlan` or ``None``
-    (plan here)."""
+def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
+                 cplan: StencilPlan, mesh: Mesh, gaxes,
+                 grid_plan: Optional[StencilGridPlan],
+                 block_i: Optional[int], block_j: Optional[int],
+                 plan_kind: str, sweeps: int, path: str, mode: str,
+                 overlap: str, interpret: bool) -> Callable[[], jax.Array]:
+    """Plan and tune :func:`stencil_sharded`'s process-grid route
+    (multi-axis ``axes`` and/or ``overlap="on"``) inside the
+    ``stencil.select`` ``span``; return the call that runs it.  Split out
+    to keep the entry point readable.  ``gaxes`` is the resolved (ai, aj,
+    ak) triple, ``grid_plan`` a caller-supplied :class:`StencilGridPlan` or
+    ``None`` (plan here)."""
     m, n, p = a.shape[-3:]
     apps = spec.sweep_apps
     per = tuple(spec.bc[d][0].kind == "periodic" for d in range(3))
@@ -324,12 +343,8 @@ def _grid_dispatch(a: jax.Array, w: jax.Array, spec: StencilSpec,
                     f"stencil_grid_sharding(..., sweeps={sweeps * apps})")
     if grid_plan.total_shards <= 1:
         # every axis fell back: same single-device fallback as the 1-D path
-        if mode == "wavefront":
-            from .sweeps import stencil_wavefront
-            return stencil_wavefront(a, w, spec, sweeps=sweeps,
-                                     plan=plan_kind, interpret=interpret)
-        return stencil_apply(a, w, spec, plan=plan_kind, sweeps=sweeps,
-                             path=path, interpret=interpret)
+        return _fallback(a, w, spec, plan_kind, sweeps, path, mode,
+                         interpret)
     names = grid_plan.axes
     hs = tuple(grid_plan.halo[d] if names[d] is not None else 0
                for d in range(3))
@@ -395,11 +410,14 @@ def _grid_dispatch(a: jax.Array, w: jax.Array, spec: StencilSpec,
             bj = None               # external j/k ghosts: tiling disallowed
     elif rpath == "auto":
         rpath = "stream"
+    run_overlap = "on" if use_overlap else "off"
+    annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj,
+             overlap=run_overlap)
     fn = _grid_sharded_fn(cplan, mesh, names, bi, bj, sweeps, interpret,
                           grid_plan.halo, grid_plan.local,
                           grid_plan.n_shards, (m, n, p), grid_plan.spec,
-                          rpath, run_mode, "on" if use_overlap else "off")
-    return fn(a4, wf).reshape(a.shape)
+                          rpath, run_mode, run_overlap)
+    return functools.partial(_launch, fn, a4, wf, a.shape)
 
 
 def stencil_sharded(a: jax.Array, w: jax.Array,
@@ -463,6 +481,39 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
     j-tiled, or too thin (``M / n_shards < 2 * radius * sweep_apps *
     sweeps``).
     """
+    with TraceAnnotation(SPAN_SHARDED):
+        with TraceAnnotation(SPAN_SELECT) as span:
+            run = _select_sharded(span, a, w, stencil, mesh, axis, block_i,
+                                  block_j, plan, sweeps, path, mode, bc,
+                                  interpret, shard_plan, guard, axes,
+                                  overlap)
+        return run()
+
+
+def _launch(fn, a4: jax.Array, wf: jax.Array, shape) -> jax.Array:
+    """Call the jitted shard_map program ``fn`` inside the
+    ``stencil.launch`` span."""
+    with TraceAnnotation(SPAN_LAUNCH):
+        return fn(a4, wf).reshape(shape)
+
+
+def _fallback(a, w, spec, plan, sweeps, path, mode, interpret):
+    """The single-device entry that runs a call the planner declined to
+    shard (it brings its own spans)."""
+    if mode == "wavefront":
+        from .sweeps import stencil_wavefront
+        return functools.partial(stencil_wavefront, a, w, spec,
+                                 sweeps=sweeps, plan=plan,
+                                 interpret=interpret)
+    return functools.partial(stencil_apply, a, w, spec, plan=plan,
+                             sweeps=sweeps, path=path, interpret=interpret)
+
+
+def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
+                    sweeps, path, mode, bc, interpret, shard_plan, guard,
+                    axes, overlap) -> Callable[[], jax.Array]:
+    """:func:`stencil_sharded`'s host-side choice, made inside the
+    ``stencil.select`` ``span``: the call that runs it."""
     if isinstance(plan, StencilShardPlan):
         raise TypeError(
             "stencil_sharded(plan=...) now selects the execution-plan kind "
@@ -488,12 +539,11 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
         policy = as_guard(policy_src)
         if policy is not None:
             gspec = spec.with_bc(bc) if bc is not None else spec
-            return guarded_sharded(a, w, gspec, policy, mesh=mesh, axis=axis,
-                                   block_i=block_i, block_j=block_j,
-                                   plan=plan, sweeps=sweeps, path=path,
-                                   mode=mode, interpret=interpret,
-                                   shard_plan=shard_plan, axes=axes,
-                                   overlap=overlap)
+            return functools.partial(
+                guarded_sharded, a, w, gspec, policy, mesh=mesh, axis=axis,
+                block_i=block_i, block_j=block_j, plan=plan, sweeps=sweeps,
+                path=path, mode=mode, interpret=interpret,
+                shard_plan=shard_plan, axes=axes, overlap=overlap)
     if spec.guard != "off":
         spec = spec.with_guard("off")   # guards never reach the trace
     if bc is not None:
@@ -534,9 +584,9 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
         mesh = jax.make_mesh((jax.device_count(),), (name,))
     if grid_mode:
         gaxes = tuple(axes) if axes is not None else (axis, None, None)
-        return _grid_dispatch(a, w, spec, cplan, mesh, gaxes, grid_plan,
-                              block_i, block_j, plan, sweeps, path, mode,
-                              overlap, interpret)
+        return _select_grid(span, a, w, spec, cplan, mesh, gaxes, grid_plan,
+                            block_i, block_j, plan, sweeps, path, mode,
+                            overlap, interpret)
     m, n, p = a.shape[-3:]
     ri = spec.radius[0]
     periodic_i = spec.bc[0][0].kind == "periodic"
@@ -556,12 +606,7 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
         # An explicit block_i is sized for the halo-extended local slab; it
         # generally doesn't divide M, so let the cost model choose here --
         # the same call must work whatever the device count.
-        if mode == "wavefront":
-            from .sweeps import stencil_wavefront
-            return stencil_wavefront(a, w, spec, sweeps=sweeps, plan=plan,
-                                     interpret=interpret)
-        return stencil_apply(a, w, spec, plan=plan, sweeps=sweeps,
-                             path=path, interpret=interpret)
+        return _fallback(a, w, spec, plan, sweeps, path, mode, interpret)
 
     batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
     a4 = a.reshape(batch, m, n, p)
@@ -599,6 +644,7 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
         bj = bj if bj is not None else bj_auto
     elif rpath == "auto":
         rpath = "stream"
+    annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj)
     fn = _sharded_fn(cplan, mesh, axis, bi, bj, sweeps, interpret, h, m_loc,
                      n_sh, m, shard_plan.spec, rpath, run_mode)
-    return fn(a4, wf).reshape(a.shape)
+    return functools.partial(_launch, fn, a4, wf, a.shape)

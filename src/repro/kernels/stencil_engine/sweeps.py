@@ -38,8 +38,11 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .autotune import SWEEP_MODES, autotune_sweeps, wavefront_block_i
+from .common import (SPAN_LAUNCH, SPAN_SELECT, SPAN_SWEEP_DRIVER, SPAN_TRACE,
+                     annotate)
 from .kernel import acc_dtype_for
 from .ops import call_3d_wavefront, resolve_interpret, stencil_apply
 from .plan import compile_plan
@@ -67,50 +70,55 @@ def stencil_wavefront(a: jax.Array, w: jax.Array,
     periodic-extended M for a periodic i axis).  ``bc``/``plan``/
     ``interpret`` as in :func:`~.ops.stencil_apply`.
     """
-    if sweeps < 1:
-        raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    spec = get_stencil(stencil)
-    if spec.guard != "off":
-        spec = spec.with_guard("off")   # guards never reach the trace
-    if bc is not None:
-        spec = spec.with_bc(bc)
-    if spec.ndim != 3:
-        raise ValueError(f"{spec.name}: the wavefront pipeline is "
-                         f"volumetric (ndim=3); use the fused or chained "
-                         f"mode for k-only specs")
-    cplan = compile_plan(spec, plan)
-    acc = acc_dtype_for(a.dtype)
-    if a.ndim < 3:
-        raise ValueError(f"{spec.name}: need (..., M, N, P), got {a.shape}")
-    m, n, p = a.shape[-3:]
-    wf = spec.canon_weights(w).astype(acc)
-    batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
-    a4 = a.reshape(batch, m, n, p)
-    interp = resolve_interpret(interpret)
+    with TraceAnnotation(SPAN_TRACE) as span:
+        if sweeps < 1:
+            raise ValueError(f"sweeps must be >= 1, got {sweeps}")
+        spec = get_stencil(stencil)
+        if spec.guard != "off":
+            spec = spec.with_guard("off")   # guards never reach the trace
+        if bc is not None:
+            spec = spec.with_bc(bc)
+        if spec.ndim != 3:
+            raise ValueError(f"{spec.name}: the wavefront pipeline is "
+                             f"volumetric (ndim=3); use the fused or chained "
+                             f"mode for k-only specs")
+        cplan = compile_plan(spec, plan)
+        acc = acc_dtype_for(a.dtype)
+        if a.ndim < 3:
+            raise ValueError(f"{spec.name}: need (..., M, N, P), got "
+                             f"{a.shape}")
+        m, n, p = a.shape[-3:]
+        wf = spec.canon_weights(w).astype(acc)
+        batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
+        a4 = a.reshape(batch, m, n, p)
+        interp = resolve_interpret(interpret)
 
-    # Periodic i: materialize the wrapped deep halo in HBM once per call
-    # (the pipeline walks i monotonically), run with external-halo
-    # geometry, crop the interior back out.
-    h = spec.radius[0] * spec.sweep_apps * sweeps
-    periodic_i = spec.bc[0][0].kind == "periodic"
-    if periodic_i and h:
-        if h > m:
-            raise ValueError(
-                f"{spec.name}: periodic wavefront needs the deep halo "
-                f"radius*sweep_apps*sweeps = {h} <= M = {m}; use the "
-                f"fused or chained mode")
-        a4 = jnp.concatenate([a4[:, m - h:], a4, a4[:, :h]], axis=1)
-        geom = jnp.array([-h, m], jnp.int32)
-    else:
-        geom = jnp.array([0, m], jnp.int32)
-    m_run = a4.shape[1]
-    bi = block_i
-    if bi is None:
-        bi = wavefront_block_i(m_run, n, p, a.dtype.itemsize, sweeps, cplan)
-    out = call_3d_wavefront(a4, wf, geom, cplan, bi, sweeps, interp)
-    if periodic_i and h:
-        out = out[:, h:h + m]
-    return out.reshape(a.shape)
+        # Periodic i: materialize the wrapped deep halo in HBM once per call
+        # (the pipeline walks i monotonically), run with external-halo
+        # geometry, crop the interior back out.
+        h = spec.radius[0] * spec.sweep_apps * sweeps
+        periodic_i = spec.bc[0][0].kind == "periodic"
+        if periodic_i and h:
+            if h > m:
+                raise ValueError(
+                    f"{spec.name}: periodic wavefront needs the deep halo "
+                    f"radius*sweep_apps*sweeps = {h} <= M = {m}; use the "
+                    f"fused or chained mode")
+            a4 = jnp.concatenate([a4[:, m - h:], a4, a4[:, :h]], axis=1)
+            geom = jnp.array([-h, m], jnp.int32)
+        else:
+            geom = jnp.array([0, m], jnp.int32)
+        m_run = a4.shape[1]
+        bi = block_i
+        if bi is None:
+            bi = wavefront_block_i(m_run, n, p, a.dtype.itemsize, sweeps,
+                                   cplan)
+        annotate(span, path="wavefront", block_i=bi, plan=cplan.kind,
+                 sweeps=sweeps)
+        out = call_3d_wavefront(a4, wf, geom, cplan, bi, sweeps, interp)
+        if periodic_i and h:
+            out = out[:, h:h + m]
+        return out.reshape(a.shape)
 
 
 def stencil_sweep_driver(a: jax.Array, w: jax.Array,
@@ -140,6 +148,17 @@ def stencil_sweep_driver(a: jax.Array, w: jax.Array,
     stream -> replicate -> oracle) on failure, blacklisting rungs whose
     kernels raise (see :mod:`.guard` and ``last_guard_report()``).
     """
+    with TraceAnnotation(SPAN_SWEEP_DRIVER):
+        with TraceAnnotation(SPAN_SELECT) as span:
+            run = _select_sweeps(span, a, w, stencil, sweeps, mode, block_i,
+                                 block_j, plan, path, bc, interpret, guard)
+        return run()
+
+
+def _select_sweeps(span, a, w, stencil, sweeps, mode, block_i, block_j,
+                   plan, path, bc, interpret, guard):
+    """:func:`stencil_sweep_driver`'s host-side choice, made inside the
+    ``stencil.select`` ``span``: the call that runs the chosen mode."""
     if mode not in SWEEP_MODES:
         raise ValueError(f"unknown sweep mode {mode!r}; expected one of "
                          f"{SWEEP_MODES}")
@@ -152,10 +171,10 @@ def stencil_sweep_driver(a: jax.Array, w: jax.Array,
         policy = as_guard(policy_src)
         if policy is not None:
             gspec = spec.with_bc(bc) if bc is not None else spec
-            return guarded_driver(a, w, gspec, policy, sweeps=sweeps,
-                                  mode=mode, block_i=block_i,
-                                  block_j=block_j, plan=plan, path=path,
-                                  interpret=interpret)
+            return functools.partial(
+                guarded_driver, a, w, gspec, policy, sweeps=sweeps,
+                mode=mode, block_i=block_i, block_j=block_j, plan=plan,
+                path=path, interpret=interpret)
     if bc is not None:
         spec = spec.with_bc(bc)
 
@@ -172,28 +191,34 @@ def stencil_sweep_driver(a: jax.Array, w: jax.Array,
                               interpret=interpret)
         return u
 
-    def wavefront(bi):
-        return stencil_wavefront(a, w, spec, block_i=bi, sweeps=sweeps,
-                                 plan=plan, interpret=interpret)
+    # "auto" races the modes on the sweeps-aware roofline where there is a
+    # choice (sweeps > 1, a volumetric spec); else it is the fused call.
+    sel, run_mode = None, mode
+    if mode == "auto":
+        run_mode = "fused"
+        if sweeps > 1 and spec.ndim == 3:
+            if a.ndim < 3:
+                raise ValueError(f"{spec.name}: need (..., M, N, P), got "
+                                 f"{a.shape}")
+            m, n, p = a.shape[-3:]
+            cplan = compile_plan(spec, plan)
+            sel = autotune_sweeps(m, n, p, a.dtype.itemsize, sweeps, cplan,
+                                  block_j=block_j, path=path)
+            run_mode = sel.mode
+    if sel is None:
+        annotate(span, mode=run_mode)
+    else:
+        annotate(span, mode=sel.mode, path=sel.path, block_i=sel.block_i,
+                 block_j=sel.block_j)
+    if run_mode == "chained":
+        return chained
+    if run_mode == "wavefront":
+        bi = sel.block_i if sel is not None and block_i is None else block_i
 
-    if mode == "fused" or sweeps == 1 and mode == "auto":
-        return fused()
-    if mode == "chained":
-        return chained()
-    if mode == "wavefront":
-        return wavefront(block_i)
-
-    # mode == "auto", sweeps > 1: race on the sweeps-aware roofline.
-    if spec.ndim != 3:
-        return fused()
-    if a.ndim < 3:
-        raise ValueError(f"{spec.name}: need (..., M, N, P), got {a.shape}")
-    m, n, p = a.shape[-3:]
-    cplan = compile_plan(spec, plan)
-    sel = autotune_sweeps(m, n, p, a.dtype.itemsize, sweeps, cplan,
-                          block_j=block_j, path=path)
-    if sel.mode == "wavefront":
-        return wavefront(block_i if block_i is not None else sel.block_i)
-    if sel.mode == "chained":
-        return chained()
-    return fused()
+        def wavefront():
+            with TraceAnnotation(SPAN_LAUNCH):
+                return stencil_wavefront(a, w, spec, block_i=bi,
+                                         sweeps=sweeps, plan=plan,
+                                         interpret=interpret)
+        return wavefront
+    return fused

@@ -5,12 +5,14 @@ described, not attached.  Each test lowers a public entry point with
 ``interpret=False`` and asserts the compiled program holds the Mosaic
 kernel (``tpu_custom_call``) -- the compiler refuses, among others, a kernel
 whose VMEM residency exceeds the scoped limit, which interpret mode never
-sees.  The topology is described inside a fixture (never at import), and
-every test stays in this one file, so under several test workers only the
-worker that runs this file loads the TPU library.
+sees -- and that each kernel carries its path's fixed name, which the
+device trace shows.  The topology is described inside a fixture (never at
+import), and every test stays in this one file, so under several test
+workers only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,8 @@ from repro.kernels.stencil_engine.autotune import autotune_engine
 from repro.sharding.planner import stencil_grid_sharding
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
+# A kernel's HLO instruction, named by its pallas_call's name=.
+KERNEL_NAME = re.compile(r"%(stencil_[a-z_]+)\.\d+ = ")
 N = 512
 
 
@@ -108,3 +112,31 @@ def test_grid_2x2x1_overlap_compiles(topo):
         overlap="on", interpret=False)).lower(a, w).compile().as_text()
     assert txt.count(KERNEL) == 3
     assert "collective-permute" in txt
+    assert set(KERNEL_NAME.findall(txt)) == {"stencil_stream",
+                                             "stencil_strip"}
+
+
+def _apply(stencil="stencil27", **kw):
+    return lambda a, w: stencil_apply(a, w, stencil, interpret=False, **kw)
+
+
+@pytest.mark.parametrize("kernel,call,shape,wshape", [
+    # the default 512^3 f32 program: 1 MiB planes, j-tiled
+    ("stencil_stream_jtiled", _apply(), (N, N, N), (2, 2, 2)),
+    ("stencil_stream", _apply(), (128, 128, 128), (2, 2, 2)),
+    ("stencil_replicate_jtiled", _apply(path="replicate"), (N, N, N),
+     (2, 2, 2)),
+    ("stencil_replicate", _apply(path="replicate"), (128, 128, 128),
+     (2, 2, 2)),
+    ("stencil_wavefront", lambda a, w: stencil_sweep_driver(
+        a, w, "stencil27", sweeps=4, mode="wavefront", interpret=False),
+     (N, N, N), (2, 2, 2)),
+    ("stencil_rows", _apply(stencil="stencil3"), (1024, N), (2,)),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_each_kernel_carries_its_name(one_chip, kernel, call, shape,
+                                      wshape):
+    """One fixed name per pallas_call site (the overlap strip's is checked
+    with the grid above); a program holds only its path's kernel."""
+    txt = jax.jit(call).lower(
+        *_shapes(one_chip, shape, jnp.float32, wshape)).compile().as_text()
+    assert set(KERNEL_NAME.findall(txt)) == {kernel}
