@@ -62,9 +62,10 @@ Plane streaming -- ``stencil_apply(..., path="stream")`` (default via auto)
 
 j-tiled blocking -- ``stencil_apply(..., block_j=bj)``
     Blocks become ``(1, bi, bj, P)`` with a j-halo assembled from the 3x3
-    neighbour tiles, so grids whose full N x P slab exceeds the VMEM budget
-    -- previously a hard wall -- run at all; the autotuner engages it only
-    when no full-N block fits.
+    neighbour tiles (replicated) or from two sublane-aligned halo blocks
+    of the j-neighbours (streaming), so grids whose full N x P slab exceeds
+    the VMEM budget -- previously a hard wall -- run at all; the autotuner
+    engages it only when no full-N block fits.
 
 Fused sweeps -- ``stencil_apply(..., sweeps=s)``
     Runs ``s`` Jacobi applications inside one ``pallas_call``: blocks are

@@ -64,10 +64,11 @@ the same bodies run unsharded (offset 0) and as the per-shard body of the
 halo-exchange ``shard_map`` path -- which is also what makes dirichlet /
 neumann ghosts materialize only on the boundary shards.  When ``bj`` is set the grid gains a j dimension: the
 replicated body sees the ``(2ri+1) x (2rj+1)`` neighbour tiles; the
-streaming body streams i within each j-tile (``2rj + 1`` j-neighbour views,
-so planes are fetched ``2rj + 1`` times instead of the replicated
-``(2ri+1)(2rj+1)`` -- exactly-once needs the full-N strip in scratch, which
-is the one regime j-tiling exists to avoid).
+streaming body streams i within each j-tile (the tile and two j-halo
+blocks of ``hb >= rj * sweeps * sweep_apps`` rows, so planes are fetched
+``1 + 2 hb / bj`` times instead of the replicated ``(2ri+1)(2rj+1)`` -- exactly-once
+needs the full-N strip in scratch, which is the one regime j-tiling exists
+to avoid).
 """
 
 from __future__ import annotations
@@ -504,12 +505,15 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
     ``refs`` is ``(*views, geom_ref, w_ref, o_ref, scr_ref)``.  Untiled
     (``bj is None``): ``views`` is one identity-mapped block ``(1, bi, N,
     P)`` and the grid's trailing dim runs ``nbi + 1`` steps; j-tiled:
-    ``views`` are the ``2rj + 1`` j-neighbour tiles ``(1, bi, bj, P)`` and
-    the grid is ``(B, nbj, nbi + 1)`` with i innermost, so the stream
-    restarts per j-tile.  ``scr_ref`` is VMEM scratch of ``bi + h`` input
-    planes (``h = ri * sweeps``) carried across grid steps: planes
-    ``[0, h)`` are the tail of the block before the previous one (zeros
-    above the domain), planes ``[h, h + bi)`` are the previous block.
+    ``views`` are the left j-halo block ``(1, bi, hb, P)``, the tile ``(1,
+    bi, bj, P)`` and the right j-halo block (``hb`` rows from
+    :func:`~.ops.halo_block_rows`, at least the ``hj``-deep halo; the tile
+    alone when ``hj == 0``), and the grid is ``(B, nbj, nbi + 1)`` with i
+    innermost, so the stream restarts per j-tile.  ``scr_ref`` is VMEM
+    scratch of ``bi + h`` input planes (``h = ri * sweeps``) carried
+    across grid steps: planes ``[0, h)`` are the tail of the block before
+    the previous one (zeros above the domain), planes ``[h, h + bi)`` are
+    the previous block.
 
     Step 0 primes the window; step ``t >= 1`` assembles the working strip
     ``[scratch | head h planes of block t]`` (at ``t == nbi`` the clamped
@@ -566,13 +570,11 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
         hj = rj * s * apps
         t = pl.program_id(2)
         j_blk = pl.program_id(1)
-        jm, jc, jp = (views[rj + d][0] if hj else views[rj][0]
-                      for d in (-1, 0, 1))
-        cur = _concat_halo(jm, jc, jp, hj, 1)              # (bi, bj+2hj, P)
+        row = views if hj else views * 3    # (left halo, tile, right halo)
+        cur = _concat_halo(*(v[0] for v in row), hj, 1)    # (bi, bj+2hj, P)
         if var:
-            wjm, wjc, wjp = (wviews[rj + d][...] if hj else wviews[rj][...]
-                             for d in (-1, 0, 1))
-            wcur = _concat_halo(wjm, wjc, wjp, hj, 2)      # (nw, bi, bj+2hj, P)
+            wrow = wviews if hj else wviews * 3
+            wcur = _concat_halo(*(v[...] for v in wrow), hj, 2)
         j0 = j_blk * bj - hj
 
     if wrap_i:

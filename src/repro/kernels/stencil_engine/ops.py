@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.profiler import TraceAnnotation
 
 from .autotune import (PATH_KINDS, autotune_blocks, autotune_engine,
-                       pick_block_rows)
+                       pick_block_rows, sublane_rows)
 from .common import (KERNEL_REPLICATE, KERNEL_REPLICATE_JTILED, KERNEL_ROWS,
                      KERNEL_STREAM, KERNEL_STREAM_JTILED, KERNEL_STRIP,
                      KERNEL_WAVEFRONT, SPAN_APPLY, SPAN_LAUNCH, SPAN_SELECT,
@@ -92,6 +92,20 @@ def _neighbor_imap(di: int, dj: int, nbi: int, nbj: int,
     return f
 
 
+def halo_block_rows(hj: int, bj: int, itemsize: int) -> int:
+    """Rows of each j-halo block the j-tiled stream kernel stages beside a
+    ``bj``-wide tile: the smallest multiple of the dtype's sublane tile that
+    covers the ``hj``-deep halo and divides ``bj``, else ``bj`` itself (the
+    neighbour tiles whole).  0 when the spec has no j halo."""
+    if hj == 0:
+        return 0
+    sub = sublane_rows(itemsize)
+    for hb in range(-(-hj // sub) * sub, bj, sub):
+        if bj % hb == 0:
+            return hb
+    return bj
+
+
 def _validate_blocks(m: int, n: int, bi: int, bj: Optional[int],
                      sweeps: int, radius, apps: int = 1) -> None:
     """``apps`` is the spec's applications per sweep (2 for red-black
@@ -126,8 +140,9 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
     input is a single identity-mapped operand -- each plane is fetched from
     HBM exactly once per call (the final clamped step re-presents the last
     block, which Pallas revisiting semantics keep DMA-free); j-tiled, the
-    ``2rj + 1`` j-neighbour views stream i within each j-tile (``2rj + 1``
-    fetches per plane vs the replicated path's ``(2ri+1)(2rj+1)``).
+    tile and two j-halo blocks of :func:`halo_block_rows` rows stream i
+    within each j-tile, so a plane is fetched ``1 + 2 hb / bj`` times (vs
+    the replicated path's ``(2ri+1)(2rj+1)``).
 
     A periodic i axis (unless ``external_i_halo`` -- the sharded ring
     already materialized the wrap) adds one more lead-in step and walks the
@@ -192,42 +207,46 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
             name=KERNEL_STREAM,
         )(a4, geom, wf)
 
-    nbj = n // bj
     hj = rj * sweeps * plan.spec.sweep_apps
     block = (1, bi, bj, p)
+    # Beside the tile, only its j-halo is staged: the left block's tail and
+    # the right block's head hj rows, in blocks of hb rows (sublane-aligned
+    # and dividing bj; hb == bj stages the neighbour tiles whole).  Index
+    # maps count in hb-row blocks; clamped edge blocks land on positions the
+    # ghost fill / interior mask overwrites, periodic ones wrap.
+    hb = halo_block_rows(hj, bj, a4.dtype.itemsize)
 
-    def jmap(dj: int):
+    def jmap(rows: int, d: int, lead: Optional[int]):
+        """Index map of the block of ``rows`` j-rows that lies ``d`` such
+        blocks past the first row of tile ``j``."""
         def f(bb, j, t):
-            return (bb, imap_t(t), _edge_index(j, dj, nbj, per_j), 0)
+            return (bb if lead is None else lead, imap_t(t),
+                    _edge_index(j * (bj // rows), d, n // rows, per_j), 0)
         return f
 
-    def wjmap(dj: int):
-        def f(bb, j, t):
-            return (0, imap_t(t), _edge_index(j, dj, nbj, per_j), 0)
-        return f
+    def views(lead_shape, lead=None):
+        """Specs of (left halo, tile, right halo), or of the tile alone."""
+        def view(rows, d):
+            return pl.BlockSpec(lead_shape + (rows, p), jmap(rows, d, lead))
+        if not hj:
+            return [view(bj, 0)]
+        return [view(hb, -1), view(bj, 0), view(hb, bj // hb)]
 
-    # The full 2rj+1 j-neighbourhood is staged (the cost model's canonical
-    # j-tiled streaming traffic, (2rj+2) bytes/pt); with bj >= rj*sweeps
-    # validated, the kernel body only reads the +-1 tiles' halo slices --
-    # narrowing the staging to match is a possible future optimization that
-    # would also have to move bytes_per_point/_views off their
-    # radius-canonical accounting.
-    in_specs = [pl.BlockSpec(block, jmap(dj))
-                for dj in range(-rj, rj + 1)]
+    in_specs = views((1, bi))
+    n_views = len(in_specs)
     in_specs += [pl.BlockSpec(geom.shape, lambda bb, j, t: (0,))]
     scratch = [pltpu.VMEM((bi + hi, bj + 2 * hj, p), a4.dtype)]
     if var:
-        in_specs += [pl.BlockSpec((wf.shape[0], bi, bj, p), wjmap(dj))
-                     for dj in range(-rj, rj + 1)]
+        in_specs += views((wf.shape[0], bi), lead=0)
         scratch.append(pltpu.VMEM((wf.shape[0], bi + hi, bj + 2 * hj, p),
                                   wf.dtype))
-        w_args = [wf] * (2 * rj + 1)
+        w_args = [wf] * n_views
     else:
         in_specs += [pl.BlockSpec(wf.shape, lambda bb, j, t: (0,))]
         w_args = [wf]
     return pl.pallas_call(
         kern,
-        grid=(b, nbj, steps),          # i innermost: the stream restarts
+        grid=(b, n // bj, steps),      # i innermost: the stream restarts
         in_specs=in_specs,             # (and re-primes) per j-tile
         out_specs=pl.BlockSpec(
             block, lambda bb, j, t: (bb, omap_t(t), j, 0)),
@@ -236,7 +255,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
         name=KERNEL_STREAM_JTILED,
-    )(*([a4] * (2 * rj + 1)), geom, *w_args)
+    )(*([a4] * n_views), geom, *w_args)
 
 
 def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
@@ -552,8 +571,13 @@ def _stencil_apply_impl(a: jax.Array, w: jax.Array,
         elif rpath == "auto":
             rpath = "stream"        # pinned blocks: stream is strictly
         geom = jnp.array([0, m], jnp.int32)  # fewer HBM bytes at equal blocks
+        halo_rows = None
+        if rpath == "stream" and bj is not None:
+            halo_rows = halo_block_rows(
+                spec.radius[1] * sweeps * spec.sweep_apps, bj,
+                a.dtype.itemsize)
         annotate(span, path=rpath, block_i=bi, block_j=bj, plan=cplan.kind,
-                 sweeps=sweeps)
+                 sweeps=sweeps, halo_rows=halo_rows)
         out = call_3d(a4, wf, geom, cplan, bi, bj, sweeps, interp, rpath,
                       fault=_fault)
         return out.reshape(a.shape)

@@ -139,6 +139,24 @@ def test_apply_records_its_trace_time_choice(tmp_path):
                         "plan": compile_plan("stencil27").kind, "sweeps": 1}
 
 
+@pytest.mark.parametrize("n,bj,dtype,want", [
+    (32, 16, jnp.float32, 8),       # the halo blocks engaged
+    (32, 16, jnp.bfloat16, 16),     # bf16 at bj=16: whole neighbour tiles
+    (24, 12, jnp.float32, 12),      # 8 does not divide 12: whole tiles
+], ids=["f32", "bf16-fallback", "bj12-fallback"])
+def test_jtiled_stream_trace_carries_halo_rows(tmp_path, n, bj, dtype, want):
+    """``halo_rows`` is the height of the j-halo blocks staged beside each
+    tile: 8 for f32 when the narrowing engages, ``bj`` on fallback."""
+    jax.clear_caches()
+    u = field((8, n, 128)).astype(dtype)
+    spans = record(tmp_path, lambda: stencil_apply(
+        u, W27, "stencil27", block_i=4, block_j=bj, path="stream",
+        bc="clamp"))
+    (trace,) = [s for s in spans if s[0] == common.SPAN_TRACE]
+    assert trace[3]["path"] == "stream" and trace[3]["block_j"] == bj
+    assert trace[3]["halo_rows"] == want
+
+
 class _Refuses:
     def set_metadata(self, **_):
         raise AssertionError("span arguments set with no profiler active")

@@ -2,8 +2,9 @@
 (bit-exact on f64 / integer-valued data, to tolerance in f32/bf16) across
 masks x sweeps x j-tiling -- at radius 1 and radius 2 (star13/box125, with
 their 2*sweeps-deep streaming window, 5-view replicated halo, and
-radius*sweeps sharded halo exchange) -- the streaming cost model's
-bytes-per-point acceptance numbers, path plumbing (autotune_engine /
+radius*sweeps sharded halo exchange) -- the j-tiled stream path's halo blocks
+against the reference, the streaming cost model's bytes-per-point
+acceptance numbers, path plumbing (autotune_engine /
 sharded), the interpret=None platform default, compile_plan memoization,
 and the non-divisible-block / sweeps-deeper-than-block error messages."""
 
@@ -23,7 +24,8 @@ from repro.kernels import (autotune_engine, bytes_per_point, compile_plan,
                            get_stencil, spec_from_mask, stencil_apply,
                            stencil_ref)
 from repro.kernels.stencil_engine.autotune import _fits, _step_time
-from repro.kernels.stencil_engine.ops import default_interpret
+from repro.kernels.stencil_engine.ops import (default_interpret,
+                                              halo_block_rows)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RNG = np.random.default_rng(11)
@@ -393,3 +395,59 @@ def test_sharded_stream_two_devices_subprocess():
                          env=env, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "stream sharded ok" in out.stdout
+
+
+@pytest.mark.parametrize("hj,bj,itemsize,want", [
+    (1, 128, 4, 8),      # stencil27, one sweep: one f32 sublane tile
+    (1, 16, 4, 8),
+    (4, 16, 4, 8),       # four fused sweeps still fit one tile
+    (9, 32, 4, 16),      # deeper than a tile: the next multiple
+    (1, 64, 2, 16),      # bf16 packs 16 rows a tile
+    (9, 24, 4, 24),      # 16 does not divide 24: whole neighbour tiles
+    (1, 12, 4, 12),      # 8 does not divide 12: whole neighbour tiles
+    (1, 16, 2, 16),      # bf16 at bj=16: hb would be bj
+    (1, 8, 4, 8),        # f32 at bj=8: hb would be bj
+    (0, 16, 4, 0),       # no j halo: the tile alone
+])
+def test_halo_block_rows_rule(hj, bj, itemsize, want):
+    """The j-halo block is the smallest sublane multiple that covers the
+    halo and divides the tile, else the whole neighbour tile."""
+    assert halo_block_rows(hj, bj, itemsize) == want
+
+
+@pytest.mark.parametrize("name,sweeps,bj,n,dtype,bc,var,hb", [
+    ("stencil27", 1, 16, 32, jnp.float32, None, False, 8),
+    ("stencil27", 4, 16, 32, jnp.float32, None, False, 8),
+    ("star13", 1, 16, 32, jnp.float32, None, False, 8),
+    ("stencil27", 2, 16, 32, jnp.float32, "periodic", False, 8),
+    ("stencil27", 2, 16, 32, jnp.float32, None, True, 8),
+    ("stencil27", 1, 16, 32, jnp.bfloat16, None, False, 16),
+    ("stencil27", 1, 12, 24, jnp.float32, None, False, 12),
+], ids=["s27-f32", "s27-fused-s4", "star13-r2", "periodic-j", "var-coef",
+        "bf16-fallback", "bj12-fallback"])
+def test_jtiled_stream_halo_blocks_match_ref(name, sweeps, bj, n, dtype, bc,
+                                            var, hb):
+    """The j-tiled stream path stages the tile and two ``hb``-row j-halo
+    blocks (whole neighbour tiles on fallback); on integer-valued data it
+    equals the reference, across the periodic seam too.  bf16 rounds once,
+    at the output."""
+    spec = get_stencil(name)
+    if var:
+        spec = spec.with_coef("var")
+    if bc is not None:
+        spec = spec.with_bc(bc)
+    hj = spec.radius[1] * sweeps * spec.sweep_apps
+    assert halo_block_rows(hj, bj, jnp.dtype(dtype).itemsize) == hb
+    shape = (8, n, 16)
+    rng = np.random.default_rng(14)
+    a = jnp.asarray(rng.integers(-4, 5, shape), jnp.float32)
+    w_shape = (spec.n_weights,) + shape if var else spec.w_shape
+    w = jnp.asarray(rng.integers(1, 4, w_shape), jnp.float32)
+    got = stencil_apply(a.astype(dtype), w, spec, block_i=4, block_j=bj,
+                        sweeps=sweeps, path="stream")
+    want = stencil_ref(a, w, spec, sweeps=sweeps)
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=2 ** -8, atol=0)
