@@ -50,14 +50,18 @@ SPAN_SHARDED = "stencil.sharded"
 ENTRY_SPANS = (SPAN_APPLY, SPAN_SWEEP_DRIVER, SPAN_SHARDED)
 # The host-side choice of an entry (spec, BC and guard resolution, the
 # plan, the sweep mode and blocks), up to the executor call; its arguments
-# carry the decision where the host makes one.
+# carry the decision where the host makes one.  The sharded entry's also
+# carry its shard geometry: on the i chain ``halo`` (planes exchanged each
+# side) and ``local_rows``; on a process grid ``halo`` and ``local``, the
+# per-axis (i, j, k) triples.
 SPAN_SELECT = "stencil.select"
 # The call into the jitted executor until it returns: JAX's dispatch and the
 # runtime's enqueue.
 SPAN_LAUNCH = "stencil.launch"
 # Inside the jitted bodies: it runs only while JAX traces them, so each span
 # is one retrace (and compile or compile-cache load), with the decision made
-# at trace time as its arguments.
+# at trace time as its arguments (``halo_rows`` on j-tiled stream calls, the
+# one-device and the shard bodies alike).
 SPAN_TRACE = "stencil.trace"
 SPANS = ENTRY_SPANS + (SPAN_SELECT, SPAN_LAUNCH, SPAN_TRACE)
 

@@ -106,6 +106,17 @@ def halo_block_rows(hj: int, bj: int, itemsize: int) -> int:
     return bj
 
 
+def staged_halo_rows(spec: StencilSpec, path: str, bj: Optional[int],
+                     sweeps: int, itemsize: int) -> Optional[int]:
+    """:func:`halo_block_rows` of a call on ``path`` with j-tile ``bj``, as
+    ``stencil.trace`` spans record it: ``None`` unless the call is j-tiled
+    on the stream path."""
+    if path != "stream" or bj is None:
+        return None
+    return halo_block_rows(spec.radius[1] * sweeps * spec.sweep_apps, bj,
+                           itemsize)
+
+
 def _validate_blocks(m: int, n: int, bi: int, bj: Optional[int],
                      sweeps: int, radius, apps: int = 1) -> None:
     """``apps`` is the spec's applications per sweep (2 for red-black
@@ -571,13 +582,9 @@ def _stencil_apply_impl(a: jax.Array, w: jax.Array,
         elif rpath == "auto":
             rpath = "stream"        # pinned blocks: stream is strictly
         geom = jnp.array([0, m], jnp.int32)  # fewer HBM bytes at equal blocks
-        halo_rows = None
-        if rpath == "stream" and bj is not None:
-            halo_rows = halo_block_rows(
-                spec.radius[1] * sweeps * spec.sweep_apps, bj,
-                a.dtype.itemsize)
         annotate(span, path=rpath, block_i=bi, block_j=bj, plan=cplan.kind,
-                 sweeps=sweeps, halo_rows=halo_rows)
+                 sweeps=sweeps, halo_rows=staged_halo_rows(
+                     spec, rpath, bj, sweeps, a.dtype.itemsize))
         out = call_3d(a4, wf, geom, cplan, bi, bj, sweeps, interp, rpath,
                       fault=_fault)
         return out.reshape(a.shape)

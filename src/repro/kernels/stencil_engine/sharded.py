@@ -46,7 +46,7 @@ from .common import (SPAN_LAUNCH, SPAN_SELECT, SPAN_SHARDED, SPAN_TRACE,
                      annotate)
 from .kernel import acc_dtype_for
 from .ops import (call_3d, call_3d_strip, call_3d_wavefront,
-                  resolve_interpret, stencil_apply)
+                  resolve_interpret, staged_halo_rows, stencil_apply)
 from .plan import StencilPlan, compile_plan
 from .spec import StencilSpec, get_stencil
 
@@ -79,12 +79,15 @@ def _mesh_key(mesh: Mesh) -> tuple:
             tuple(mesh.devices.shape), tuple(mesh.axis_names))
 
 
-def _traced(body: Callable, **args) -> Callable:
+def _traced(body: Callable, cplan: StencilPlan, **args) -> Callable:
     """The shard_map body ``body`` inside the ``stencil.trace`` span, which
-    records the trace-time decision ``args``: it runs once per trace."""
+    records the trace-time decision ``args`` and, on a j-tiled stream call,
+    ``halo_rows`` for the traced field's dtype: it runs once per trace."""
     def local_fn(a_loc: jax.Array, wf_: jax.Array) -> jax.Array:
         with TraceAnnotation(SPAN_TRACE) as span:
-            annotate(span, **args)
+            annotate(span, halo_rows=staged_halo_rows(
+                cplan.spec, args["path"], args["block_j"], args["sweeps"],
+                a_loc.dtype.itemsize), **args)
             return body(a_loc, wf_)
     return local_fn
 
@@ -145,8 +148,8 @@ def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
 
     w_spec = part if var else P(None)
     fn = jax.jit(jax.shard_map(
-        _traced(local_fn, mode=mode, path=path, block_i=bi, block_j=bj,
-                plan=cplan.kind, sweeps=sweeps),
+        _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
+                block_j=bj, plan=cplan.kind, sweeps=sweeps),
         mesh=mesh, in_specs=(part, w_spec), out_specs=part,
         check_vma=False))
     _SHARDED_CACHE[key] = fn
@@ -298,8 +301,8 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
     local_fn = local_overlap if overlap == "on" else local_serial
     w_spec = part if var else P(None)
     fn = jax.jit(jax.shard_map(
-        _traced(local_fn, mode=mode, path=path, block_i=bi, block_j=bj,
-                plan=cplan.kind, sweeps=sweeps, overlap=overlap),
+        _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
+                block_j=bj, plan=cplan.kind, sweeps=sweeps, overlap=overlap),
         mesh=mesh, in_specs=(part, w_spec), out_specs=part,
         check_vma=False))
     _SHARDED_CACHE[key] = fn
@@ -410,9 +413,11 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
             bj = None               # external j/k ghosts: tiling disallowed
     elif rpath == "auto":
         rpath = "stream"
+    if use_overlap:
+        bj = None                   # the overlap executor's calls are untiled
     run_overlap = "on" if use_overlap else "off"
     annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj,
-             overlap=run_overlap)
+             overlap=run_overlap, halo=hs, local=grid_plan.local)
     fn = _grid_sharded_fn(cplan, mesh, names, bi, bj, sweeps, interpret,
                           grid_plan.halo, grid_plan.local,
                           grid_plan.n_shards, (m, n, p), grid_plan.spec,
@@ -644,7 +649,8 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
         bj = bj if bj is not None else bj_auto
     elif rpath == "auto":
         rpath = "stream"
-    annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj)
+    annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj,
+             halo=h, local_rows=m_loc)
     fn = _sharded_fn(cplan, mesh, axis, bi, bj, sweeps, interpret, h, m_loc,
                      n_sh, m, shard_plan.spec, rpath, run_mode)
     return functools.partial(_launch, fn, a4, wf, a.shape)

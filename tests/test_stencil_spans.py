@@ -200,3 +200,56 @@ def test_sharded_spans_on_four_devices(tmp_path):
     traces = [s for s in spans if s[0] == common.SPAN_TRACE]
     assert len(traces) == 1 and inside(traces[0], first)
     assert traces[0][3]["mode"] == sel[0][3]["mode"]
+    # the chain's geometry: one plane exchanged each side, 8 local rows
+    assert sel[0][3]["halo"] == 1 and sel[0][3]["local_rows"] == 8
+    # halo_rows only on a j-tiled stream call
+    tiled = traces[0][3]["path"] == "stream" and "block_j" in traces[0][3]
+    assert ("halo_rows" in traces[0][3]) == tiled
+
+
+SHARDED_CASE = textwrap.dedent("""
+    import json, sys
+    import jax
+    sys.path.insert(0, {tests!r})
+    from test_stencil_spans import W27, field, record
+    from repro.kernels import stencil_sharded
+    assert len(jax.devices()) == 4, jax.devices()
+    mesh = jax.make_mesh({mesh_shape!r}, {mesh_axes!r})
+    u = field({shape!r})
+    def call():
+        return stencil_sharded(u, W27, "stencil27", mesh=mesh, bc="clamp",
+                               **{kwargs!r})
+    print(json.dumps(record({trace!r}, call)))
+""")
+
+
+@pytest.mark.parametrize("mesh_shape,mesh_axes,shape,kwargs,select,trace", [
+    # the i chain, j-tiled by a passed block_j: the narrowing engages
+    ((4,), ("i",), (32, 32, 128), dict(axis="i", block_j=16),
+     dict(halo=1, local_rows=8), dict(block_j=16, halo_rows=8)),
+    # a 2x2x1 process grid: per-axis (i, j, k) halo and local extents
+    ((2, 2), ("i", "j"), (16, 16, 128), dict(axes=("i", "j", None)),
+     dict(halo=str((1, 1, 0)), local=str((8, 8, 128))), dict()),
+], ids=["chain-jtiled", "grid-2x2x1"])
+def test_sharded_spans_carry_the_shard_geometry(tmp_path, mesh_shape,
+                                                mesh_axes, shape, kwargs,
+                                                select, trace):
+    """The sharded entry's ``stencil.select`` carries the exchange depth
+    and the local extents; the shard body's ``stencil.trace`` carries
+    ``halo_rows`` on a j-tiled stream call and not otherwise.  Span
+    arguments that are tuples are recorded as their text."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(SRC),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    script = SHARDED_CASE.format(tests=str(Path(__file__).parent),
+                                 mesh_shape=mesh_shape, mesh_axes=mesh_axes,
+                                 shape=shape, kwargs=kwargs,
+                                 trace=str(tmp_path))
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    spans = [tuple(s) for s in json.loads(proc.stdout.splitlines()[-1])]
+    (sel,) = [s[3] for s in spans if s[0] == common.SPAN_SELECT]
+    (tr,) = [s[3] for s in spans if s[0] == common.SPAN_TRACE]
+    assert {k: sel.get(k) for k in select} == select
+    assert {k: tr.get(k) for k in trace} == trace
+    assert ("halo_rows" in tr) == ("halo_rows" in trace)

@@ -140,3 +140,32 @@ def test_each_kernel_carries_its_name(one_chip, kernel, call, shape,
     txt = jax.jit(call).lower(
         *_shapes(one_chip, shape, jnp.float32, wshape)).compile().as_text()
     assert set(KERNEL_NAME.findall(txt)) == {kernel}
+
+
+def test_four_chip_chain_at_the_cells_size_compiles(topo):
+    """The ``j27-f32-x4`` deployment: 4096x512x512 f32 on a (4,) mesh on
+    i, each chip's 1024 planes extended by one exchanged plane a side to
+    1026 and swept by the j-tiled stream kernel.  Two halo
+    collective-permutes; the slab, the kernel's output and the crop fit a
+    chip with the field and its output.  The ``jax.jit`` wrapper folds
+    ``stencil_sharded``'s reshapes around its jitted program into this one
+    compile, so the two whole-shard copies an eager call makes (one each
+    side) do not show here."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("i",),
+                axis_types=(AxisType.Auto,))
+    a = jax.ShapeDtypeStruct((4 * 2 * N, N, N), jnp.float32,
+                             sharding=NamedSharding(mesh, P("i")))
+    w = jax.ShapeDtypeStruct((2, 2, 2), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(lambda a, w: stencil_sharded(
+        a, w, "stencil27", mesh=mesh, axis="i", bc="clamp",
+        interpret=False)).lower(a, w).compile()
+    txt = compiled.as_text()
+    assert set(KERNEL_NAME.findall(txt)) == {"stencil_stream_jtiled"}
+    assert txt.count(KERNEL) == 1
+    assert len(re.findall(r"%collective-permute-start(?:\.\d+)? = ",
+                          txt)) == 2
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 8 << 30          # ~4.15 GB: field, output, slab, kernel out
