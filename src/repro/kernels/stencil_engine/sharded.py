@@ -28,12 +28,12 @@ indefinitely).
 from __future__ import annotations
 
 import functools
+import math
 from collections import OrderedDict
 from typing import Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -92,6 +92,23 @@ def _traced(body: Callable, cplan: StencilPlan, **args) -> Callable:
     return local_fn
 
 
+def _cache_program(key: tuple, body: Callable) -> Callable:
+    """Jit, cache under ``key`` and return the program around the
+    shard_map ``body``.  The program takes the caller's ``(..., M, N, P)``
+    field as it is: it flattens the batch dimensions to the body's
+    ``(batch, M, N, P)`` and reshapes the result back inside the one
+    compile, where both reshapes lower to bitcasts.  Run eagerly, each
+    would be an executable of its own that copies the whole shard."""
+    def program(a: jax.Array, wf: jax.Array) -> jax.Array:
+        batch = math.prod(a.shape[:-3])
+        return body(a.reshape(batch, *a.shape[-3:]), wf).reshape(a.shape)
+
+    fn = _SHARDED_CACHE[key] = jax.jit(program)
+    while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
+        _SHARDED_CACHE.popitem(last=False)
+    return fn
+
+
 def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
                 bj: Optional[int], sweeps: int, interpret: bool, h: int,
                 m_loc: int, n_sh: int, m: int, part, path: str = "stream",
@@ -147,15 +164,11 @@ def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
         return out[:, h:h + m_loc]
 
     w_spec = part if var else P(None)
-    fn = jax.jit(jax.shard_map(
+    return _cache_program(key, jax.shard_map(
         _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
                 block_j=bj, plan=cplan.kind, sweeps=sweeps),
         mesh=mesh, in_specs=(part, w_spec), out_specs=part,
         check_vma=False))
-    _SHARDED_CACHE[key] = fn
-    while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
-        _SHARDED_CACHE.popitem(last=False)
-    return fn
 
 
 _AXIS_LABEL = ("i", "j", "k")
@@ -300,15 +313,11 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
 
     local_fn = local_overlap if overlap == "on" else local_serial
     w_spec = part if var else P(None)
-    fn = jax.jit(jax.shard_map(
+    return _cache_program(key, jax.shard_map(
         _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
                 block_j=bj, plan=cplan.kind, sweeps=sweeps, overlap=overlap),
         mesh=mesh, in_specs=(part, w_spec), out_specs=part,
         check_vma=False))
-    _SHARDED_CACHE[key] = fn
-    while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
-        _SHARDED_CACHE.popitem(last=False)
-    return fn
 
 
 def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
@@ -363,8 +372,6 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
                          f"periodic unsharded i axis inside a process grid "
                          f"(no local pre-extension there); shard i or use "
                          f"mode='fused'")
-    batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
-    a4 = a.reshape(batch, m, n, p)
     acc = acc_dtype_for(a.dtype)
     var = spec.coef == "var"
     wf = spec.canon_weights(w, (m, n, p) if var else None).astype(acc)
@@ -422,7 +429,7 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
                           grid_plan.halo, grid_plan.local,
                           grid_plan.n_shards, (m, n, p), grid_plan.spec,
                           rpath, run_mode, run_overlap)
-    return functools.partial(_launch, fn, a4, wf, a.shape)
+    return functools.partial(_launch, fn, a, wf)
 
 
 def stencil_sharded(a: jax.Array, w: jax.Array,
@@ -495,11 +502,11 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
         return run()
 
 
-def _launch(fn, a4: jax.Array, wf: jax.Array, shape) -> jax.Array:
+def _launch(fn, a: jax.Array, wf: jax.Array) -> jax.Array:
     """Call the jitted shard_map program ``fn`` inside the
     ``stencil.launch`` span."""
     with TraceAnnotation(SPAN_LAUNCH):
-        return fn(a4, wf).reshape(shape)
+        return fn(a, wf)
 
 
 def _fallback(a, w, spec, plan, sweeps, path, mode, interpret):
@@ -613,8 +620,6 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
         # the same call must work whatever the device count.
         return _fallback(a, w, spec, plan, sweeps, path, mode, interpret)
 
-    batch = int(np.prod(a.shape[:-3])) if a.ndim > 3 else 1
-    a4 = a.reshape(batch, m, n, p)
     acc = acc_dtype_for(a.dtype)
     # var weights canonicalize to (n_weights, M, N, P) and shard with the
     # domain (same PartitionSpec: the i axis sits at axis 1 either way)
@@ -653,4 +658,4 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
              halo=h, local_rows=m_loc)
     fn = _sharded_fn(cplan, mesh, axis, bi, bj, sweeps, interpret, h, m_loc,
                      n_sh, m, shard_plan.spec, rpath, run_mode)
-    return functools.partial(_launch, fn, a4, wf, a.shape)
+    return functools.partial(_launch, fn, a, wf)

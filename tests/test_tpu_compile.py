@@ -147,10 +147,11 @@ def test_four_chip_chain_at_the_cells_size_compiles(topo):
     i, each chip's 1024 planes extended by one exchanged plane a side to
     1026 and swept by the j-tiled stream kernel.  Two halo
     collective-permutes; the slab, the kernel's output and the crop fit a
-    chip with the field and its output.  The ``jax.jit`` wrapper folds
-    ``stencil_sharded``'s reshapes around its jitted program into this one
-    compile, so the two whole-shard copies an eager call makes (one each
-    side) do not show here."""
+    chip with the field and its output.  The program ``stencil_sharded``
+    launches takes the caller's (M, N, P) field as it is and reshapes it
+    to (1, M, N, P) and back inside, so both reshapes lower to bitcasts:
+    no copy of a whole shard, on either side of the shard body.  (The
+    ``jax.jit`` wrapper only lets shape structs stand for the field.)"""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("i",),
                 axis_types=(AxisType.Auto,))
     a = jax.ShapeDtypeStruct((4 * 2 * N, N, N), jnp.float32,
@@ -165,6 +166,8 @@ def test_four_chip_chain_at_the_cells_size_compiles(topo):
     assert txt.count(KERNEL) == 1
     assert len(re.findall(r"%collective-permute-start(?:\.\d+)? = ",
                           txt)) == 2
+    shard = rf"f32\[(?:1,)?{2 * N},{N},{N}\]"
+    assert not re.search(rf"= {shard}\{{[^}}]*\}} copy\(", txt)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
