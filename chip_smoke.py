@@ -51,8 +51,7 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.kernels import (autotune_sweeps, compile_plan,  # noqa: E402
                            last_guard_report, stencil_apply, stencil_sharded,
                            stencil_sweep_driver)
-from repro.sharding.planner import (stencil_grid_sharding,  # noqa: E402
-                                    stencil_halo_sharding)
+from repro.sharding.planner import stencil_grid_sharding  # noqa: E402
 
 N = 512                      # one-chip domain edge: 512^3 f32 = 0.5 GiB
 FOUR_CHIP_SHAPE = (1024, 512, 512)
@@ -260,9 +259,10 @@ def four_chips(seed: int) -> None:
     a = int_field(jax.random.PRNGKey(seed), shape,
                   sharding=NamedSharding(chain, P("i", None, None)))
     for bc in ("clamp", "periodic"):
-        plan = stencil_halo_sharding(shape[0], chain, axis="i", sweeps=1,
-                                     radius=1, periodic=bc == "periodic")
-        check(plan.n_shards == 4,
+        plan = stencil_grid_sharding(shape, chain, axes=("i", None, None),
+                                     sweeps=1, radius=1,
+                                     periodic=(bc == "periodic",) * 3)
+        check(plan.n_shards == (4, 1, 1),
               f"chain {bc}: planner did not shard i 4 ways: {plan.notes}")
         want = ref(a, t27, 1, bc == "periodic", 1)
         run_phase(f"stencil_sharded stencil27 i-chain (4,) {bc} f32",

@@ -115,12 +115,13 @@ Red-black Gauss-Seidel -- ``spec.with_ordering("redblack")``
     wavefront kernels, and the sharded halo depth all account for.
 
 Sharded execution -- :func:`stencil_sharded`
-    ``shard_map`` over the i-axis: the partition plan (divisibility, halo
-    depth, PlanNotes) comes from
-    ``repro.sharding.planner.stencil_halo_sharding``; shards exchange
-    ``radius * sweep_apps * sweeps`` halo rows via ``lax.ppermute`` --
+    ``shard_map`` over a process grid whose 1-D case, ``axis=`` (the grid
+    ``(axis, None, None)``), is the i-chain: the partition plan
+    (divisibility, halo depth, PlanNotes) comes from
+    ``repro.sharding.planner.stencil_grid_sharding``; shards exchange
+    ``radius * sweep_apps * sweeps`` halo planes via ``lax.ppermute`` --
     a chain whose edge shards take their boundary ghosts locally, or a
-    closed ring when the i axis is periodic -- and run the same fused
+    closed ring when the axis is periodic -- and run the same fused
     kernel (or, with ``mode="wavefront"``, the temporal-wavefront
     pipeline) *once*: ``s`` sweeps cost one exchange round, shard-edge
     strips redundantly recomputed from the deep halo, with

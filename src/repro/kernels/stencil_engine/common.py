@@ -51,9 +51,8 @@ ENTRY_SPANS = (SPAN_APPLY, SPAN_SWEEP_DRIVER, SPAN_SHARDED)
 # The host-side choice of an entry (spec, BC and guard resolution, the
 # plan, the sweep mode and blocks), up to the executor call; its arguments
 # carry the decision where the host makes one.  The sharded entry's also
-# carry its shard geometry: on the i chain ``halo`` (planes exchanged each
-# side) and ``local_rows``; on a process grid ``halo`` and ``local``, the
-# per-axis (i, j, k) triples.
+# carry its shard geometry: ``halo`` (planes exchanged each side) and
+# ``local``, the per-axis (i, j, k) triples, and ``overlap``.
 SPAN_SELECT = "stencil.select"
 # The call into the jitted executor until it returns: JAX's dispatch and the
 # runtime's enqueue.

@@ -1,21 +1,25 @@
-"""Multi-device stencil execution: shard_map over the i-axis + halo exchange.
+"""Multi-device stencil execution: shard_map over a process grid + halo
+exchange.
 
-The partition plan comes from ``repro.sharding.planner.stencil_halo_sharding``
-(divisibility and halo-depth checks, PlanNote audit trail).  Each shard owns a
-contiguous slab of i-rows and trades ``radius * sweeps`` halo rows with its
-neighbours via ``lax.ppermute``.  The exchange topology follows the spec's
-i-axis boundary condition: a *chain* for the non-periodic BCs (edge shards
-receive zeros, which the kernel's global-geometry ghost fill then turns into
-the clamp / dirichlet / neumann boundary -- so those BCs materialize only on
-the boundary shards) or a closed *ring* for periodic (shard 0 and shard N-1
-trade wrap-around halos).  Each shard
-then runs the *same* fused plan-compiled Pallas kernel as the
-single-device path -- by default the plane-streaming body, so the shard_map
-body also fetches each local plane from HBM exactly once and carries the
-halo window in VMEM scratch (``path="replicate"`` stays available as the
-parity escape hatch, and j-tiled blocking engages when the local N x P slab
-exceeds the VMEM budget); the kernel's geometry operand (global row offset,
-global M) keeps the interior/boundary masking correct across shard seams.
+The partition plan comes from ``repro.sharding.planner.stencil_grid_sharding``
+(per-axis divisibility and halo-depth checks, PlanNote audit trail).  Each
+sharded domain axis (i, j, k) is split into contiguous slabs, and each shard
+trades ``radius * sweeps`` ghost planes a side with its neighbours on that
+axis via ``lax.ppermute``; the 1-D i-chain is the ``(p, 1, 1)`` grid, with
+j and k whole.  The exchange topology follows each axis's boundary
+condition: a *chain* for the non-periodic BCs (edge shards receive zeros,
+which the kernel's global-geometry ghost fill then turns into the clamp /
+dirichlet / neumann boundary -- so those BCs materialize only on the
+boundary shards) or a closed *ring* for periodic (shard 0 and shard N-1
+trade wrap-around halos).  Each shard then runs the *same* fused
+plan-compiled Pallas kernel as the single-device path -- by default the
+plane-streaming body, so the shard_map body also fetches each local plane
+from HBM exactly once and carries the halo window in VMEM scratch
+(``path="replicate"`` stays available as the parity escape hatch, and
+j-tiled blocking engages when the local N x P slab exceeds the VMEM
+budget); the kernel's geometry operand (global row offset, global M, and
+the j/k offsets where those axes are sharded) keeps the interior/boundary
+masking correct across shard seams.
 
 The compiled shard_map program is memoized in a small bounded cache keyed on
 the mesh's *device ids + topology + axis names* (plus the execution
@@ -24,7 +28,6 @@ entry and the cache can never retain more than ``_SHARDED_CACHE_MAX``
 programs (the old ``lru_cache`` keyed on ``Mesh`` kept up to 64 meshes alive
 indefinitely).
 """
-
 from __future__ import annotations
 
 import functools
@@ -37,9 +40,7 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...sharding.planner import (StencilGridPlan, StencilShardPlan,
-                                 stencil_grid_sharding,
-                                 stencil_halo_sharding)
+from ...sharding.planner import StencilGridPlan, stencil_grid_sharding
 from .autotune import (PATH_KINDS, autotune_engine, autotune_sweeps,
                        exchange_bytes_per_point, wavefront_block_i)
 from .common import (SPAN_LAUNCH, SPAN_SELECT, SPAN_SHARDED, SPAN_TRACE,
@@ -107,68 +108,6 @@ def _cache_program(key: tuple, body: Callable) -> Callable:
     while len(_SHARDED_CACHE) > _SHARDED_CACHE_MAX:
         _SHARDED_CACHE.popitem(last=False)
     return fn
-
-
-def _sharded_fn(cplan: StencilPlan, mesh: Mesh, axis: str, bi: int,
-                bj: Optional[int], sweeps: int, interpret: bool, h: int,
-                m_loc: int, n_sh: int, m: int, part, path: str = "stream",
-                mode: str = "fused"):
-    """Build (and cache) the jitted shard_map program for one geometry, so
-    repeated calls don't retrace the inner pallas_call."""
-    key = (cplan, _mesh_key(mesh), axis, bi, bj, sweeps, interpret, h,
-           m_loc, n_sh, m, part, path, mode, _HALO_FAULT_VERSION[0])
-    fn = _SHARDED_CACHE.get(key)
-    if fn is not None:
-        _SHARDED_CACHE.move_to_end(key)
-        return fn
-    periodic_i = cplan.spec.bc[0][0].kind == "periodic"
-    if periodic_i:
-        # ring: shard 0's low halo wraps around from shard n-1 (and vice
-        # versa) -- the periodic BC *is* the wrap-around link
-        lo_perm = [(i, (i + 1) % n_sh) for i in range(n_sh)]
-        hi_perm = [((i + 1) % n_sh, i) for i in range(n_sh)]
-    else:
-        # chain: edge shards get zeros; the kernel's global-geometry ghost
-        # fill turns them into the clamp / dirichlet / neumann boundary
-        # (so non-periodic BCs only materialize on the boundary shards)
-        lo_perm = [(i, i + 1) for i in range(n_sh - 1)]
-        hi_perm = [(i + 1, i) for i in range(n_sh - 1)]
-
-    var = cplan.spec.coef == "var"
-
-    def _halo_ext(x: jax.Array) -> jax.Array:
-        # x is (lead, M_loc, N, P): the i axis sits at axis 1 for both the
-        # batched field (lead = batch) and the canonicalized coefficient
-        # stack (lead = n_weights), so one exchange serves both.
-        lo = jax.lax.ppermute(x[:, -h:], axis, lo_perm)
-        hi = jax.lax.ppermute(x[:, :h], axis, hi_perm)
-        if _HALO_FAULT[0] is not None:
-            lo, hi = _HALO_FAULT[0](lo, hi, axis="i")
-        return jnp.concatenate([lo, x, hi], axis=1)
-
-    def local_fn(a_loc: jax.Array, wf_: jax.Array) -> jax.Array:
-        idx = jax.lax.axis_index(axis)
-        ext = _halo_ext(a_loc)
-        wx = _halo_ext(wf_) if var else wf_
-        geom = jnp.stack([idx * m_loc - h,
-                          jnp.int32(m)]).astype(jnp.int32)
-        if mode == "wavefront":
-            # one radius*sweep_apps*sweeps-deep exchange already happened
-            # (ext); the pipeline redundantly recomputes the shard-edge
-            # strip exactly like the fused deep halo does
-            out = call_3d_wavefront(ext, wx, geom, cplan, bi, sweeps,
-                                    interpret)
-        else:
-            out = call_3d(ext, wx, geom, cplan, bi, bj, sweeps, interpret,
-                          path, external_i_halo=True)
-        return out[:, h:h + m_loc]
-
-    w_spec = part if var else P(None)
-    return _cache_program(key, jax.shard_map(
-        _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
-                block_j=bj, plan=cplan.kind, sweeps=sweeps),
-        mesh=mesh, in_specs=(part, w_spec), out_specs=part,
-        check_vma=False))
 
 
 _AXIS_LABEL = ("i", "j", "k")
@@ -245,8 +184,9 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
                 if names[d] is not None else jnp.int32(0) for d in range(3)]
 
     def _geom(i_row, offs):
-        return jnp.stack([i_row, jnp.int32(m_gl), offs[1] - hs[1],
-                          offs[2] - hs[2]]).astype(jnp.int32)
+        # (gi0, M), and the j/k offsets only where a j/k halo is exchanged
+        jk = [offs[1] - hs[1], offs[2] - hs[2]] if ext_j or ext_k else []
+        return jnp.stack([i_row, jnp.int32(m_gl), *jk]).astype(jnp.int32)
 
     def local_serial(a_loc: jax.Array, wf_: jax.Array) -> jax.Array:
         offs = _offsets()
@@ -326,12 +266,11 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
                  block_i: Optional[int], block_j: Optional[int],
                  plan_kind: str, sweeps: int, path: str, mode: str,
                  overlap: str, interpret: bool) -> Callable[[], jax.Array]:
-    """Plan and tune :func:`stencil_sharded`'s process-grid route
-    (multi-axis ``axes`` and/or ``overlap="on"``) inside the
+    """Plan and tune a :func:`stencil_sharded` call inside the
     ``stencil.select`` ``span``; return the call that runs it.  Split out
     to keep the entry point readable.  ``gaxes`` is the resolved (ai, aj,
-    ak) triple, ``grid_plan`` a caller-supplied :class:`StencilGridPlan` or
-    ``None`` (plan here)."""
+    ak) triple (``(axis, None, None)`` for the 1-D i-chain), ``grid_plan``
+    a caller-supplied :class:`StencilGridPlan` or ``None`` (plan here)."""
     m, n, p = a.shape[-3:]
     apps = spec.sweep_apps
     per = tuple(spec.bc[d][0].kind == "periodic" for d in range(3))
@@ -378,8 +317,6 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
     use_overlap = (overlap == "on" and names[0] is not None and hs[0] > 0
                    and m_l >= 2 * hs[0] and block_j is None
                    and mode != "wavefront")
-    ebpp = exchange_bytes_per_point(a.dtype.itemsize, hs, grid_plan.local,
-                                    sweeps, spec.n_weights if var else 0)
     # overlap tunes for the interior call (resident m_l planes); serialized
     # tunes for the one fully extended slab
     m_tune = m_l if use_overlap else m_ext
@@ -394,6 +331,9 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
         if use_overlap:
             run_mode = "fused"      # overlap is a fused-mode executor
         else:
+            ebpp = exchange_bytes_per_point(a.dtype.itemsize, hs,
+                                            grid_plan.local, sweeps,
+                                            spec.n_weights if var else 0)
             sel = autotune_sweeps(m_tune, n_ext, p_ext, a.dtype.itemsize,
                                   sweeps, cplan, block_j=bj, path=path,
                                   external_i_halo=names[0] is not None,
@@ -439,14 +379,15 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
                     block_j: Optional[int] = None, plan: str = "auto",
                     sweeps: int = 1, path: str = "auto", mode: str = "fused",
                     bc=None, interpret: Optional[bool] = None,
-                    shard_plan: Union[StencilShardPlan, StencilGridPlan,
-                                      None] = None,
+                    shard_plan: Optional[StencilGridPlan] = None,
                     guard=None, axes=None,
                     overlap: str = "off") -> jax.Array:
-    """Halo-exchange execution of ``stencil_apply`` over a mesh axis.
+    """Halo-exchange execution of ``stencil_apply`` over a process grid.
 
     ``a`` is ``(..., M, N, P)`` (volumetric specs only); ``mesh`` defaults to
-    a 1-D mesh over every visible device.  Returns the same value as the
+    a 1-D mesh over every visible device.  ``axis`` shards i alone over
+    that mesh axis: the 1-D i-chain, which is the process grid
+    ``axes=(axis, None, None)``.  Returns the same value as the
     single-device path; falls back to it when the planner declines to shard.
     ``path`` selects the per-shard data-movement strategy exactly as in
     ``stencil_apply`` -- ``"auto"`` streams the halo-extended local slab
@@ -473,7 +414,11 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
 
     ``axes`` generalizes ``axis`` to an (pi, pj, pk) *process grid*: a
     triple of mesh-axis names (``None`` = that domain axis stays whole),
-    e.g. ``axes=("x", "y", "z")`` on a 2x2x2 mesh.  Face ghosts are
+    e.g. ``axes=("x", "y", "z")`` on a 2x2x2 mesh.  ``shard_plan`` takes
+    a :class:`~repro.sharding.StencilGridPlan` from
+    :func:`~repro.sharding.stencil_grid_sharding` in place of the plan
+    made here; its halo must cover ``radius * sweep_apps * sweeps`` on
+    every sharded axis.  Face ghosts are
     exchanged per axis in the order j, k, i on the progressively extended
     slab, so corner/edge ghosts arrive transitively without diagonal
     sends (see :func:`~repro.sharding.stencil_grid_sharding`); per-axis
@@ -526,11 +471,6 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
                     axes, overlap) -> Callable[[], jax.Array]:
     """:func:`stencil_sharded`'s host-side choice, made inside the
     ``stencil.select`` ``span``: the call that runs it."""
-    if isinstance(plan, StencilShardPlan):
-        raise TypeError(
-            "stencil_sharded(plan=...) now selects the execution-plan kind "
-            "(auto/direct/cse/factored); pass the partition plan as "
-            "shard_plan=... instead")
     if path not in PATH_KINDS:
         raise ValueError(f"unknown path {path!r}; expected one of "
                          f"{PATH_KINDS}")
@@ -570,18 +510,11 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
                          f"(ndim=3) spec")
     if a.ndim < 3:
         raise ValueError(f"{spec.name}: need (..., M, N, P), got {a.shape}")
-    grid_plan = shard_plan if isinstance(shard_plan, StencilGridPlan) else None
-    grid_mode = (grid_plan is not None or overlap == "on"
-                 or (axes is not None
-                     and (axes[0] is None
-                          or any(ax is not None for ax in axes[1:]))))
-    if axes is not None and not grid_mode:
-        axis = axes[0]              # 1-D spelling of the grid API
     if mesh is None:
         multi = ((axes is not None
                   and sum(1 for ax in axes if ax is not None) > 1)
-                 or (grid_plan is not None
-                     and sum(1 for ax in grid_plan.axes
+                 or (shard_plan is not None
+                     and sum(1 for ax in shard_plan.axes
                              if ax is not None) > 1))
         if multi:
             raise ValueError(
@@ -591,71 +524,10 @@ def _select_sharded(span, a, w, stencil, mesh, axis, block_i, block_j, plan,
         name = axis
         if axes is not None and axes[0] is not None:
             name = axes[0]
-        elif grid_plan is not None and grid_plan.axes[0] is not None:
-            name = grid_plan.axes[0]
+        elif shard_plan is not None and shard_plan.axes[0] is not None:
+            name = shard_plan.axes[0]
         mesh = jax.make_mesh((jax.device_count(),), (name,))
-    if grid_mode:
-        gaxes = tuple(axes) if axes is not None else (axis, None, None)
-        return _select_grid(span, a, w, spec, cplan, mesh, gaxes, grid_plan,
-                            block_i, block_j, plan, sweeps, path, mode,
-                            overlap, interpret)
-    m, n, p = a.shape[-3:]
-    ri = spec.radius[0]
-    periodic_i = spec.bc[0][0].kind == "periodic"
-    apps = spec.sweep_apps              # red-black doubles the halo depth
-    if shard_plan is None:
-        shard_plan = stencil_halo_sharding(m, mesh, axis=axis,
-                                           sweeps=sweeps * apps,
-                                           radius=ri, periodic=periodic_i)
-    if shard_plan.n_shards > 1 and shard_plan.halo < ri * sweeps * apps:
-        raise ValueError(
-            f"shard_plan.halo={shard_plan.halo} rows/side cannot cover "
-            f"radius {ri} x sweeps {sweeps} x sweep_apps {apps} = "
-            f"{ri * sweeps * apps}; re-plan with "
-            f"stencil_halo_sharding(..., sweeps={sweeps * apps}, "
-            f"radius={ri})")
-    if shard_plan.n_shards <= 1:
-        # An explicit block_i is sized for the halo-extended local slab; it
-        # generally doesn't divide M, so let the cost model choose here --
-        # the same call must work whatever the device count.
-        return _fallback(a, w, spec, plan, sweeps, path, mode, interpret)
-
-    acc = acc_dtype_for(a.dtype)
-    # var weights canonicalize to (n_weights, M, N, P) and shard with the
-    # domain (same PartitionSpec: the i axis sits at axis 1 either way)
-    dom = (m, n, p) if spec.coef == "var" else None
-    wf = spec.canon_weights(w, dom).astype(acc)
-    h, m_loc, n_sh = shard_plan.halo, shard_plan.local_rows, shard_plan.n_shards
-    m_ext = m_loc + 2 * h
-    if block_i is not None and m_ext % block_i != 0:
-        raise ValueError(
-            f"sharded block_i={block_i} must divide the halo-extended local "
-            f"slab (M/n_shards + 2*radius*sweeps = {m_loc} + {2 * h} = "
-            f"{m_ext}); omit block_i to let the cost model choose")
-    bi, bj, rpath = block_i, block_j, path
-    run_mode = mode
-    if run_mode == "auto":
-        sel = autotune_sweeps(m_ext, n, p, a.dtype.itemsize, sweeps, cplan,
-                              block_j=bj, path=path, external_i_halo=True)
-        run_mode = "wavefront" if sel.mode == "wavefront" else "fused"
-    if run_mode == "wavefront":
-        if bj is not None:
-            raise ValueError(f"{spec.name}: the wavefront mode is untiled "
-                             f"(full-N blocks); omit block_j or use "
-                             f"mode='fused'")
-        if bi is None:
-            bi = wavefront_block_i(m_ext, n, p, a.dtype.itemsize, sweeps,
-                                   cplan)
-        rpath = "wavefront"
-    elif bi is None:
-        rpath, bi, bj_auto = autotune_engine(m_ext, n, p, a.dtype.itemsize,
-                                             sweeps=sweeps, plan=cplan,
-                                             block_j=bj, path=path)
-        bj = bj if bj is not None else bj_auto
-    elif rpath == "auto":
-        rpath = "stream"
-    annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj,
-             halo=h, local_rows=m_loc)
-    fn = _sharded_fn(cplan, mesh, axis, bi, bj, sweeps, interpret, h, m_loc,
-                     n_sh, m, shard_plan.spec, rpath, run_mode)
-    return functools.partial(_launch, fn, a, wf)
+    gaxes = tuple(axes) if axes is not None else (axis, None, None)
+    return _select_grid(span, a, w, spec, cplan, mesh, gaxes, shard_plan,
+                        block_i, block_j, plan, sweeps, path, mode, overlap,
+                        interpret)

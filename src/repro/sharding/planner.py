@@ -173,75 +173,6 @@ def decode_state_sharding(cfg: ArchConfig, state_shapes: Any, mesh: Mesh
 
 
 @dataclasses.dataclass
-class StencilShardPlan:
-    """How to split a stencil grid's i-axis over a mesh axis.
-
-    ``n_shards == 1`` means "don't shard" (indivisible M or shards too thin
-    for the halo) -- callers fall back to single-device execution; the
-    reason is recorded as a PlanNote, Table-2 style.  ``periodic`` turns
-    the halo exchange into a ring: shard 0's low halo wraps around from
-    shard ``n-1`` (and vice versa) instead of arriving as zeros."""
-    axis: str
-    n_shards: int
-    halo: int                 # rows exchanged per side == radius * sweeps
-    local_rows: int
-    spec: Any                 # PartitionSpec for a (B, M, N, P) operand
-    notes: List[PlanNote]
-    periodic: bool = False    # i-axis BC is periodic: ring, not chain
-
-
-def stencil_halo_sharding(m: int, mesh: Mesh, axis: str = "data",
-                          sweeps: int = 1, radius: int = 1,
-                          periodic: bool = False) -> StencilShardPlan:
-    """Plan i-axis halo-exchange sharding for an (..., M, N, P) stencil grid.
-
-    Each shard owns ``M / n`` contiguous i-rows and exchanges ``radius *
-    sweeps`` halo rows with each neighbour per fused call (a radius-R
-    operator applied ``sweeps`` times needs ``R`` rows per sweep).
-    ``periodic=True`` (the i-axis boundary condition is periodic) closes
-    the exchange into a ring with wrap-around links between shard 0 and
-    shard ``n - 1``; non-periodic edge BCs never travel -- dirichlet /
-    neumann ghosts materialize only on the boundary shards, from the
-    kernel's global-geometry fill.  Falls back to an unsharded plan -- with
-    the reason noted -- when M doesn't divide or local rows couldn't cover
-    the halo."""
-    n = _mesh_axis_size(mesh, axis)
-    halo = radius * sweeps
-    notes: List[PlanNote] = []
-
-    def fallback(reason: str) -> StencilShardPlan:
-        notes.append(PlanNote("stencil/i-axis", (m,), None, reason))
-        return StencilShardPlan(axis, 1, halo, m, P(None, None, None, None),
-                                notes, periodic)
-
-    if n <= 1:
-        return fallback(f"axis {axis!r} has size {n}; running unsharded")
-    if m % n != 0:
-        return fallback(f"M={m} not divisible by {axis}={n}; replicating")
-    local = m // n
-    if local < halo:
-        # Too-thin shards are a configuration error, not a graceful
-        # degradation: the deep-halo exchange would need rows the owning
-        # shard does not hold, so silently replicating here used to hide a
-        # mesh that can never shard this problem.
-        raise ValueError(
-            f"stencil_halo_sharding: M={m} over mesh axis {axis!r}={n} "
-            f"leaves {local} local rows/shard, fewer than the "
-            f"{halo}-row halo (radius {radius} x sweeps {sweeps}); "
-            f"need M // n_shards >= radius * sweeps -- use a smaller "
-            f"mesh axis, a larger M, or fewer fused sweeps")
-    topo = ("ring (periodic wrap between shard 0 and shard "
-            f"{n - 1})" if periodic else
-            "chain (edge shards take boundary ghosts locally)")
-    notes.append(PlanNote(
-        "stencil/i-axis", (m,), P(None, axis, None, None),
-        f"i-axis split {n} ways x {local} rows, halo {halo}/side "
-        f"(radius {radius} x sweeps {sweeps}), {topo}"))
-    return StencilShardPlan(axis, n, halo, local,
-                            P(None, axis, None, None), notes, periodic)
-
-
-@dataclasses.dataclass
 class StencilGridPlan:
     """How to split a stencil grid over an (pi, pj, pk) process grid.
 
@@ -250,7 +181,7 @@ class StencilGridPlan:
     per-axis ``n_shards`` / ``halo`` / ``local`` describe its slab.  An
     axis whose mesh axis has size 1 or whose extent does not divide falls
     back to unsharded with a PlanNote; a shard too thin to cover its own
-    halo *raises* (same contract as :func:`stencil_halo_sharding`).
+    halo *raises*.  ``axes=(a, None, None)`` is the 1-D i-chain.
     ``spec`` is the combined ``P(None, ai, aj, ak)`` for a ``(B, M, N, P)``
     operand.  ``periodic[d]`` closes axis ``d``'s exchange into a ring."""
     axes: Tuple[Optional[str], Optional[str], Optional[str]]
@@ -275,10 +206,10 @@ def stencil_grid_sharding(shape: Tuple[int, int, int], mesh: Mesh,
     ``axes`` names the mesh axis carrying each domain axis (i, j, k) --
     ``None`` leaves that axis whole.  Per sharded axis the shard owns
     ``extent / n`` contiguous planes and exchanges ``radius * sweeps``
-    ghost planes per side (callers fold ``sweep_apps`` into ``sweeps``,
-    as with :func:`stencil_halo_sharding`).  Corner/edge ghosts need no
-    diagonal sends: the executor exchanges one axis at a time on the
-    progressively extended slab (j, then k, then i), so each later
+    ghost planes per side (callers fold ``sweep_apps`` into ``sweeps``).
+    ``axes=(a, None, None)`` plans the 1-D i-chain.  Corner/edge ghosts
+    need no diagonal sends: the executor exchanges one axis at a time on
+    the progressively extended slab (j, then k, then i), so each later
     exchange carries the earlier axes' ghost columns and the diagonal
     data arrives transitively.  A per-axis ``periodic`` entry closes that
     axis's exchange into a ring.  Indivisible extents and size-1 mesh

@@ -269,13 +269,14 @@ def test_bc_periodic_sharded_two_devices_subprocess():
         assert jax.device_count() == 2, jax.devices()
         from repro.kernels import (dirichlet, stencil_apply, stencil_sharded,
                                    get_stencil)
-        from repro.sharding.planner import stencil_halo_sharding
+        from repro.sharding.planner import stencil_grid_sharding
         rng = np.random.default_rng(7)
         a = jnp.asarray(rng.integers(-4, 5, (16, 12, 16)), jnp.float32)
         mesh = jax.make_mesh((2,), ("data",))
-        plan = stencil_halo_sharding(16, mesh, sweeps=2, radius=2,
-                                     periodic=True)
-        assert plan.periodic and "ring" in plan.notes[-1].reason
+        plan = stencil_grid_sharding((16, 12, 16), mesh,
+                                     axes=("data", None, None), sweeps=2,
+                                     radius=2, periodic=(True, True, True))
+        assert plan.periodic[0] and "ring" in plan.notes[-1].reason
         for name in ("stencil27", "star13"):
             spec = get_stencil(name)
             w = jnp.asarray(rng.integers(1, 4, spec.w_shape), jnp.float32)

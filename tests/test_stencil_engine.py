@@ -344,12 +344,16 @@ def test_sharded_fn_cache_keyed_on_device_ids_and_bounded():
     m1 = jax.make_mesh((1,), ("data",))
     m2 = Mesh(np.asarray(jax.devices()[:1]), ("data",))
     assert sh._mesh_key(m1) == sh._mesh_key(m2)
-    f1 = sh._sharded_fn(plan, m1, "data", 4, None, 1, True, 1, 8, 1, 8, part)
-    f2 = sh._sharded_fn(plan, m2, "data", 4, None, 1, True, 1, 8, 1, 8, part)
+    names, halos, nsh = ("data", None, None), (1, 1, 1), (1, 1, 1)
+
+    def build(mesh, m):
+        return sh._grid_sharded_fn(plan, mesh, names, 4, None, 1, True,
+                                   halos, (m, 8, 8), nsh, (m, 8, 8), part)
+    f1 = build(m1, 8)
+    f2 = build(m2, 8)
     assert f1 is f2
     for k in range(sh._SHARDED_CACHE_MAX + 8):
-        sh._sharded_fn(plan, m1, "data", 4, None, 1, True, 1, 8 + k, 1,
-                       8 + k, part)
+        build(m1, 8 + k)
     assert len(sh._SHARDED_CACHE) <= sh._SHARDED_CACHE_MAX
 
 
@@ -365,10 +369,11 @@ def test_autotuner_properties():
 
 
 def test_planner_fallbacks_and_plan():
-    from repro.sharding.planner import stencil_halo_sharding
+    from repro.sharding.planner import stencil_grid_sharding
     mesh = jax.make_mesh((1,), ("data",))
-    plan = stencil_halo_sharding(16, mesh, sweeps=1)
-    assert plan.n_shards == 1                      # 1 device: unsharded
+    plan = stencil_grid_sharding((16, 8, 8), mesh, axes=("data", None, None),
+                                 sweeps=1)
+    assert plan.n_shards == (1, 1, 1)              # 1 device: unsharded
     assert any("unsharded" in n.reason for n in plan.notes)
 
 
@@ -379,15 +384,19 @@ def test_sharded_two_devices_subprocess():
         import jax, numpy as np, jax.numpy as jnp
         assert jax.device_count() == 2, jax.devices()
         from repro.kernels import stencil_apply, stencil_ref, stencil_sharded
-        from repro.sharding.planner import stencil_halo_sharding
+        from repro.sharding.planner import stencil_grid_sharding
         rng = np.random.default_rng(3)
         a = jnp.asarray(rng.standard_normal((16, 10, 16)), jnp.float32)
         w = jnp.asarray(rng.uniform(0.1, 1.0, (2, 2, 2)), jnp.float32)
         mesh = jax.make_mesh((2,), ("data",))
         for s in (1, 2):
-            plan = stencil_halo_sharding(16, mesh, sweeps=s)
-            assert plan.n_shards == 2 and plan.halo == s
+            plan = stencil_grid_sharding((16, 10, 16), mesh,
+                                         axes=("data", None, None), sweeps=s)
+            assert plan.n_shards == (2, 1, 1) and plan.halo[0] == s
             got = stencil_sharded(a, w, "stencil27", mesh=mesh, sweeps=s)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(
+                stencil_sharded(a, w, "stencil27", mesh=mesh, sweeps=s,
+                                shard_plan=plan)))
             one = stencil_apply(a, w, "stencil27", block_i=4, sweeps=s)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
             ref = stencil_ref(a, w, "stencil27", sweeps=s)

@@ -65,32 +65,31 @@ def _weights(spec_name, seed=1, dtype=jnp.float32):
 # planner validation (no devices needed beyond what the process has)
 # ---------------------------------------------------------------------------
 
-def test_thin_shard_raises_i_axis_subprocess():
-    """The satellite bugfix: a mesh axis too wide for the i extent raises
-    with the shapes in the message instead of silently planning a halo the
-    shards cannot cover."""
-    print(_run("""
-        import jax, pytest
-        from repro.sharding import stencil_halo_sharding
+THIN_SHARD = {
+    # the 1-D i-chain: 16 rows / 8 shards = 2 local rows < radius 1 x
+    # sweeps 4; an indivisible extent still falls back with a PlanNote
+    "i-axis": """
+        import jax
+        from repro.sharding import stencil_grid_sharding
         mesh = jax.make_mesh((8,), ("data",))
-        # 16 rows / 8 shards = 2 local rows < radius 1 * sweeps 4
+        axes = ("data", None, None)
         try:
-            stencil_halo_sharding(16, mesh, sweeps=4, radius=1)
+            stencil_grid_sharding((16, 8, 8), mesh, axes=axes, sweeps=4,
+                                  radius=1)
         except ValueError as e:
             msg = str(e)
-            assert "M=16" in msg and "'data'=8" in msg and "2 local" in msg
-            assert "radius * sweeps" in msg
+            assert "i-extent 16" in msg and "'data'=8" in msg
+            assert "2 local" in msg and "radius * sweeps" in msg
         else:
             raise AssertionError("thin shard did not raise")
-        # the graceful fallbacks stay graceful: indivisible extents PlanNote
-        plan = stencil_halo_sharding(17, mesh, sweeps=1, radius=1)
-        assert plan.n_shards == 1 and plan.notes
+        plan = stencil_grid_sharding((17, 8, 8), mesh, axes=axes, sweeps=1,
+                                     radius=1)
+        assert plan.n_shards == (1, 1, 1) and plan.notes
         print("thin-shard raise ok")
-    """))
-
-
-def test_thin_shard_raises_grid_axis_subprocess():
-    print(_run("""
+    """,
+    # a 2x2x2 grid too thin on j; size-1 / indivisible axes still fall
+    # back with a PlanNote
+    "grid-axis": """
         import jax
         from repro.sharding import stencil_grid_sharding
         mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"))
@@ -102,14 +101,22 @@ def test_thin_shard_raises_grid_axis_subprocess():
             assert "j-extent 4" in msg and "'y'=2" in msg
         else:
             raise AssertionError("thin grid shard did not raise")
-        # size-1 / indivisible axes still fall back with a PlanNote
         plan = stencil_grid_sharding((16, 9, 16), mesh, axes=("x", "y", "z"),
                                      sweeps=1, radius=1)
         assert plan.axes == ("x", None, "z")
         assert plan.n_shards == (2, 1, 2)
         assert any("not divisible" in n.reason for n in plan.notes)
-        print("grid thin-shard raise ok")
-    """))
+        print("thin-shard raise ok")
+    """,
+}
+
+
+@pytest.mark.parametrize("case", sorted(THIN_SHARD))
+def test_thin_shard_raises_subprocess(case):
+    """A mesh axis too wide for its extent raises with the shapes in the
+    message instead of silently planning a halo the shards cannot cover;
+    the graceful fallbacks stay graceful."""
+    assert "thin-shard raise ok" in _run(THIN_SHARD[case])
 
 
 def test_grid_plan_spec_and_locals():

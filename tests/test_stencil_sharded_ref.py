@@ -115,7 +115,8 @@ def test_sharded_geometry_is_the_cells(readings, case):
     shape, bj, narrowed = CASES[case]
     r = readings[f"{case}-clamp"]
     sel, body = r["select"], r["trace"]
-    assert sel["halo"] == 1 and sel["local_rows"] == shape[0] // 4, r
+    assert sel["halo"] == str((1, 0, 0)), r
+    assert sel["local"] == str((shape[0] // 4, *shape[1:])), r
     assert sel["block_i"] % 2 == 1 and body["block_i"] == sel["block_i"], r
     if narrowed:
         assert sel["block_j"] == body["block_j"] == bj, r

@@ -200,8 +200,9 @@ def test_sharded_spans_on_four_devices(tmp_path):
     traces = [s for s in spans if s[0] == common.SPAN_TRACE]
     assert len(traces) == 1 and inside(traces[0], first)
     assert traces[0][3]["mode"] == sel[0][3]["mode"]
-    # the chain's geometry: one plane exchanged each side, 8 local rows
-    assert sel[0][3]["halo"] == 1 and sel[0][3]["local_rows"] == 8
+    # the chain's geometry: one i plane exchanged each side, 8 local rows
+    assert sel[0][3]["halo"] == str((1, 0, 0))
+    assert sel[0][3]["local"] == str((8, 16, 128))
     # halo_rows only on a j-tiled stream call
     tiled = traces[0][3]["path"] == "stream" and "block_j" in traces[0][3]
     assert ("halo_rows" in traces[0][3]) == tiled
@@ -226,7 +227,8 @@ SHARDED_CASE = textwrap.dedent("""
 @pytest.mark.parametrize("mesh_shape,mesh_axes,shape,kwargs,select,trace", [
     # the i chain, j-tiled by a passed block_j: the narrowing engages
     ((4,), ("i",), (32, 32, 128), dict(axis="i", block_j=16),
-     dict(halo=1, local_rows=8), dict(block_j=16, halo_rows=8)),
+     dict(halo=str((1, 0, 0)), local=str((8, 32, 128))),
+     dict(block_j=16, halo_rows=8)),
     # a 2x2x1 process grid: per-axis (i, j, k) halo and local extents
     ((2, 2), ("i", "j"), (16, 16, 128), dict(axes=("i", "j", None)),
      dict(halo=str((1, 1, 0)), local=str((8, 8, 128))), dict()),

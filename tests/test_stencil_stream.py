@@ -320,15 +320,17 @@ def test_radius2_sharded_stream_two_devices_subprocess():
         import jax, numpy as np, jax.numpy as jnp
         assert jax.device_count() == 2, jax.devices()
         from repro.kernels import stencil_apply, stencil_sharded
-        from repro.sharding.planner import stencil_halo_sharding
+        from repro.sharding.planner import stencil_grid_sharding
         rng = np.random.default_rng(5)
         a = jnp.asarray(rng.integers(-4, 5, (16, 12, 16)), jnp.float32)
         mesh = jax.make_mesh((2,), ("data",))
         for name, wshape in (("star13", (3,)), ("box125", (3, 3, 3))):
             w = jnp.asarray(rng.integers(1, 4, wshape), jnp.float32)
             for s in (1, 2):
-                plan = stencil_halo_sharding(16, mesh, sweeps=s, radius=2)
-                assert plan.n_shards == 2 and plan.halo == 2 * s
+                plan = stencil_grid_sharding((16, 12, 16), mesh,
+                                             axes=("data", None, None),
+                                             sweeps=s, radius=2)
+                assert plan.n_shards == (2, 1, 1) and plan.halo[0] == 2 * s
                 st = stencil_sharded(a, w, name, mesh=mesh, sweeps=s,
                                      path="stream")
                 rp = stencil_sharded(a, w, name, mesh=mesh, sweeps=s,
@@ -353,12 +355,14 @@ def test_radius2_sharded_stream_two_devices_subprocess():
 def test_radius2_shard_plan_halo_mismatch_raises():
     """An explicit shard_plan whose halo can't cover radius * sweeps is
     rejected with a clear message instead of silently corrupting seams."""
-    from repro.sharding.planner import StencilShardPlan
+    from repro.sharding.planner import StencilGridPlan
     from jax.sharding import PartitionSpec as P
     a = jnp.zeros((16, 8, 16), jnp.float32)
     w = jnp.zeros((3,), jnp.float32)
-    bad = StencilShardPlan(axis="data", n_shards=2, halo=1, local_rows=8,
-                           spec=P(None, "data", None, None), notes=[])
+    # i sharded 2 ways with a 1-plane halo; star13 needs 2
+    bad = StencilGridPlan(axes=("data", None, None), n_shards=(2, 1, 1),
+                          halo=(1, 2, 2), local=(8, 8, 16),
+                          spec=P(None, "data", None, None), notes=[])
     from repro.kernels import stencil_sharded
     with pytest.raises(ValueError, match="halo"):
         stencil_sharded(a, w, "star13", sweeps=1, shard_plan=bad)

@@ -150,8 +150,11 @@ def test_four_chip_chain_at_the_cells_size_compiles(topo):
     chip with the field and its output.  The program ``stencil_sharded``
     launches takes the caller's (M, N, P) field as it is and reshapes it
     to (1, M, N, P) and back inside, so both reshapes lower to bitcasts:
-    no copy of a whole shard, on either side of the shard body.  (The
-    ``jax.jit`` wrapper only lets shape structs stand for the field.)"""
+    no copy of a whole shard, on either side of the shard body.  The
+    concatenation builds the 1026-plane slab and the crop takes back the
+    1024 local planes: the shapes of the 1-D chain, which runs as the
+    ``(4, 1, 1)`` process grid.  (The ``jax.jit`` wrapper only lets shape
+    structs stand for the field.)"""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("i",),
                 axis_types=(AxisType.Auto,))
     a = jax.ShapeDtypeStruct((4 * 2 * N, N, N), jnp.float32,
@@ -168,6 +171,10 @@ def test_four_chip_chain_at_the_cells_size_compiles(topo):
                           txt)) == 2
     shard = rf"f32\[(?:1,)?{2 * N},{N},{N}\]"
     assert not re.search(rf"= {shard}\{{[^}}]*\}} copy\(", txt)
+    slab = rf"f32\[(?:1,)?{2 * N + 2},{N},{N}\]"
+    assert re.search(rf"= {slab}\{{[^}}]*\}} fusion\(", txt)
+    assert re.search(rf"= {shard}\{{[^}}]*\}} slice\([^)]*\), "
+                     rf"slice=\{{[^}}]*\[1:{2 * N + 1}\]", txt)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
