@@ -60,7 +60,9 @@ SPAN_LAUNCH = "stencil.launch"
 # Inside the jitted bodies: it runs only while JAX traces them, so each span
 # is one retrace (and compile or compile-cache load), with the decision made
 # at trace time as its arguments (``halo_rows`` on j-tiled stream calls, the
-# one-device and the shard bodies alike).
+# one-device and the shard bodies alike; ``i_halo`` in a shard body whose i
+# axis is sharded: ``"operands"`` where the exchanged i planes are kernel
+# operands, ``"concat"`` where they are concatenated onto the slab).
 SPAN_TRACE = "stencil.trace"
 SPANS = ENTRY_SPANS + (SPAN_SELECT, SPAN_LAUNCH, SPAN_TRACE)
 

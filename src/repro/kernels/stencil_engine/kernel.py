@@ -499,7 +499,8 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
                             acc_dtype, wrap_i: bool = False,
                             fault: Optional[KernelFault] = None,
                             ext_j: bool = False, ext_k: bool = False,
-                            p_global: Optional[int] = None):
+                            p_global: Optional[int] = None,
+                            i_halo: bool = False):
     """Plane-streaming fused-sweep volumetric kernel (``path="stream"``).
 
     ``refs`` is ``(*views, geom_ref, w_ref, o_ref, scr_ref)``.  Untiled
@@ -524,6 +525,15 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
     the lagged output index map, and rotates the window.  Net HBM traffic:
     each input plane read once, each output plane written once.
 
+    ``i_halo=True`` (a shard whose i ghosts arrived by exchange): the two
+    exchanged ``h``-plane slabs follow the weights as operands, ``(*lo,
+    *hi)`` in the same views as the field (blocks ``(1, h, ., P)``, fetched
+    once per j-tile).  Step 0 primes the ghost planes from ``lo`` in place
+    of zeros, and the last step takes its head planes from ``hi`` in place
+    of the re-presented block's, so the stream runs over the resident
+    planes alone and ``geom_ref[0]`` is the global row of the first of
+    them.
+
     ``wrap_i=True`` (the i axis is periodic, realized here rather than by a
     pre-exchanged shard halo): the stream gains one more lead-in step and
     walks the *wrapped* block sequence ``nbi-1, 0, 1, ..., nbi-1, 0``
@@ -542,39 +552,53 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
     ``scr_ref`` -- so coefficient planes, like field planes, are fetched
     from HBM exactly once per call.  Coefficients are evaluated at the
     *output* point; the above-domain lead-in planes are zero-primed and
-    only ever feed discarded ghost outputs.
+    only ever feed discarded ghost outputs.  With ``i_halo`` the exchanged
+    coefficient slabs follow the field's, ``(*lo, *hi, *wlo, *whi)``.
     """
     var = plan.spec.coef == "var"
-    if var:
-        o_ref, scr_ref, wscr_ref = refs[-3], refs[-2], refs[-1]
-        nv = (len(refs) - 4) // 2
-        views, geom_ref = refs[:nv], refs[nv]
-        wviews = refs[nv + 1:nv + 1 + nv]
-    else:
-        o_ref, scr_ref = refs[-2], refs[-1]
-        geom_ref, w_ref = refs[-4], refs[-3]
-        views = refs[:-4]
     ri, rj, _ = plan.spec.radius
     s = sweeps
     apps = plan.spec.sweep_apps
     hi = ri * s * apps
+    hj = rj * s * apps
+    nv = 3 if bj is not None and hj else 1     # field views per operand
+    views, geom_ref = refs[:nv], refs[nv]
+    k = nv + 1
+    if var:
+        wviews, k = refs[k:k + nv], k + nv
+    else:
+        w_ref, k = refs[k], k + 1
+    n_slabs = (4 if var else 2) if i_halo else 0
+    slabs = [refs[k + q * nv:k + (q + 1) * nv]    # lo, hi (, wlo, whi)
+             for q in range(n_slabs)]
+    k += n_slabs * nv
+    o_ref, scr_ref = refs[k], refs[k + 1]
+    if var:
+        wscr_ref = refs[k + 2]
     lag = 2 if wrap_i else 1
     k0 = geom_ref[3] if ext_k else 0
+
+    def assemble(vs, field: bool):
+        """The block of views ``vs`` (field: batch axis dropped), with its
+        j-halo columns on a j-tiled call."""
+        def load(v):
+            return v[0] if field else v[...]
+        if bj is None:
+            return load(vs[0])
+        row = vs if hj else vs * 3          # (left halo, tile, right halo)
+        return _concat_halo(*(load(v) for v in row), hj, 1 if field else 2)
+
     if bj is None:
         t = pl.program_id(1)
-        cur = views[0][0]                                  # (bi, N, P)
-        if var:
-            wcur = wviews[0][...]                          # (nw, bi, N, P)
-        j0 = geom_ref[2] if ext_j else 0
     else:
-        hj = rj * s * apps
         t = pl.program_id(2)
         j_blk = pl.program_id(1)
-        row = views if hj else views * 3    # (left halo, tile, right halo)
-        cur = _concat_halo(*(v[0] for v in row), hj, 1)    # (bi, bj+2hj, P)
-        if var:
-            wrow = wviews if hj else wviews * 3
-            wcur = _concat_halo(*(v[...] for v in wrow), hj, 2)
+    cur = assemble(views, True)                     # (bi, ., P)
+    if var:
+        wcur = assemble(wviews, False)              # (nw, bi, ., P)
+    if bj is None:
+        j0 = geom_ref[2] if ext_j else 0
+    else:
         j0 = j_blk * bj - hj
 
     if wrap_i:
@@ -594,10 +618,14 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
     else:
         @pl.when(t == 0)
         def _prime():
-            # Window for output block 0: block "-1" is above the domain
-            # (zeros; the strip fill / interior mask handles them), block
-            # 0 = cur.
-            if hi:
+            # Window for output block 0: block "-1" is the exchanged low
+            # slab, or above the domain (zeros; the strip fill / interior
+            # mask handles them), block 0 = cur.
+            if hi and i_halo:
+                scr_ref[:hi] = assemble(slabs[0], True)
+                if var:
+                    wscr_ref[:, :hi] = assemble(slabs[2], False)
+            elif hi:
                 scr_ref[:hi] = jnp.zeros((hi,) + cur.shape[1:], cur.dtype)
                 if var:
                     wscr_ref[:, :hi] = jnp.zeros(
@@ -616,12 +644,25 @@ def stencil3d_stream_kernel(*refs, plan: StencilPlan, bi: int,
             fp = hi + (fault.plane % bi)
             scr_ref[fp] = jnp.full(scr_ref.shape[1:], jnp.nan, scr_ref.dtype)
 
+    def head(block, slab: int, field: bool):
+        """The ``hi`` planes after the scratch window: the head of
+        ``block``, and on the last step of an ``i_halo`` stream (whose
+        re-presented block lies before them) the high exchanged slab,
+        ``slabs[slab]``."""
+        planes = block[:hi] if field else block[:, :hi]
+        if not i_halo:
+            return planes
+        last = t == pl.num_programs(1 if bj is None else 2) - 1
+        return jnp.where(last, assemble(slabs[slab], field), planes)
+
     @pl.when(t >= lag)
     def _compute():
-        u = (jnp.concatenate([scr_ref[...], cur[:hi]], axis=0) if hi
+        u = (jnp.concatenate([scr_ref[...], head(cur, 1, True)],
+                             axis=0) if hi
              else scr_ref[...]).astype(acc_dtype)          # (bi + 2hi, ., P)
         if var:
-            w = (jnp.concatenate([wscr_ref[...], wcur[:, :hi]], axis=1)
+            w = (jnp.concatenate([wscr_ref[...],
+                                  head(wcur, 3, False)], axis=1)
                  if hi else wscr_ref[...])                 # (nw, bi + 2hi, ., P)
         else:
             w = w_ref[...]

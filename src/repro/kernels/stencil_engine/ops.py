@@ -144,7 +144,8 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
                     fault: Optional[KernelFault] = None,
                     ext_j: bool = False, ext_k: bool = False,
                     n_global: Optional[int] = None,
-                    p_global: Optional[int] = None) -> jax.Array:
+                    p_global: Optional[int] = None,
+                    i_halo: Optional[tuple] = None) -> jax.Array:
     """Wire the plane-streaming kernel: one pass over the i-blocks with one
     extra grid step, a lagged output index map, and a VMEM scratch window of
     ``bi + ri * sweeps`` input planes carried across steps.  Untiled, the
@@ -165,13 +166,22 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
     Variable-coefficient specs (``wf`` is ``(n_weights, M, N, P)``) add a
     parallel set of coefficient views under the *same* block walk plus a
     second co-rotating VMEM scratch window, so coefficient planes stream
-    exactly like field planes -- fetched once per call."""
+    exactly like field planes -- fetched once per call.
+
+    ``i_halo`` (a shard's exchanged i ghosts, implying ``external_i_halo``)
+    is ``(lo, hi)``, the ``ri * sweeps * sweep_apps`` planes below and
+    above ``a4``, plus ``(wlo, whi)`` for a variable-coefficient spec.
+    They become kernel operands, staged in the field's views under an index
+    map constant in the stream step (fetched once per j-tile), so the
+    kernel sweeps the resident planes alone: no slab extended by the ghosts
+    and no output to crop.  ``None`` wires the historical call."""
     b, m, n, p = a4.shape
     nbi = m // bi
     ri, rj, _ = plan.spec.radius
     hi = ri * sweeps * plan.spec.sweep_apps
     var = plan.spec.coef == "var"
     per_i, per_j = _periodic_axes(plan.spec)
+    external_i_halo = external_i_halo or i_halo is not None
     wrap_i = per_i and not external_i_halo and hi > 0
     steps = nbi + (2 if wrap_i else 1)
     lag = 2 if wrap_i else 1
@@ -181,7 +191,8 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
                              acc_dtype=acc_dtype_for(a4.dtype),
                              wrap_i=wrap_i, fault=fault, ext_j=ext_j,
                              ext_k=ext_k,
-                             p_global=p_global if ext_k else None)
+                             p_global=p_global if ext_k else None,
+                             i_halo=i_halo is not None)
     if wrap_i:
         def imap_t(t):
             return (t + nbi - 1) % nbi
@@ -205,6 +216,12 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
             scratch.append(pltpu.VMEM((wf.shape[0], bi + hi, n, p), wf.dtype))
         else:
             in_specs.append(pl.BlockSpec(wf.shape, lambda bb, t: (0,)))
+        if i_halo is not None:          # lo, hi (, wlo, whi): fetched once
+            in_specs += [pl.BlockSpec((1, hi, n, p),
+                                      lambda bb, t: (bb, 0, 0, 0))] * 2
+            if var:
+                in_specs += [pl.BlockSpec((wf.shape[0], hi, n, p),
+                                          lambda bb, t: (0, 0, 0, 0))] * 2
         return pl.pallas_call(
             kern,
             grid=(b, steps),
@@ -216,7 +233,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
             interpret=interpret,
             compiler_params=_TPU_PARAMS,
             name=KERNEL_STREAM,
-        )(a4, geom, wf)
+        )(a4, geom, wf, *(i_halo or ()))
 
     hj = rj * sweeps * plan.spec.sweep_apps
     block = (1, bi, bj, p)
@@ -227,21 +244,25 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
     # ghost fill / interior mask overwrites, periodic ones wrap.
     hb = halo_block_rows(hj, bj, a4.dtype.itemsize)
 
-    def jmap(rows: int, d: int, lead: Optional[int]):
+    def jmap(rows: int, d: int, lead: Optional[int], imap):
         """Index map of the block of ``rows`` j-rows that lies ``d`` such
-        blocks past the first row of tile ``j``."""
+        blocks past the first row of tile ``j``, on i-block ``imap(t)``."""
         def f(bb, j, t):
-            return (bb if lead is None else lead, imap_t(t),
+            return (bb if lead is None else lead, imap(t),
                     _edge_index(j * (bj // rows), d, n // rows, per_j), 0)
         return f
 
-    def views(lead_shape, lead=None):
+    def views(lead_shape, lead=None, imap=imap_t):
         """Specs of (left halo, tile, right halo), or of the tile alone."""
         def view(rows, d):
-            return pl.BlockSpec(lead_shape + (rows, p), jmap(rows, d, lead))
+            return pl.BlockSpec(lead_shape + (rows, p),
+                                jmap(rows, d, lead, imap))
         if not hj:
             return [view(bj, 0)]
         return [view(hb, -1), view(bj, 0), view(hb, bj // hb)]
+
+    def slab(t):
+        return 0            # an exchanged slab is one block, whatever t
 
     in_specs = views((1, bi))
     n_views = len(in_specs)
@@ -255,6 +276,12 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
     else:
         in_specs += [pl.BlockSpec(wf.shape, lambda bb, j, t: (0,))]
         w_args = [wf]
+    halo_args = []
+    if i_halo is not None:
+        in_specs += views((1, hi), imap=slab) * 2
+        if var:
+            in_specs += views((wf.shape[0], hi), lead=0, imap=slab) * 2
+        halo_args = [x for x in i_halo for _ in range(n_views)]
     return pl.pallas_call(
         kern,
         grid=(b, n // bj, steps),      # i innermost: the stream restarts
@@ -266,7 +293,7 @@ def _call_3d_stream(a4: jax.Array, wf: jax.Array, geom: jax.Array,
         interpret=interpret,
         compiler_params=_TPU_PARAMS,
         name=KERNEL_STREAM_JTILED,
-    )(*([a4] * n_views), geom, *w_args)
+    )(*([a4] * n_views), geom, *w_args, *halo_args)
 
 
 def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
@@ -275,7 +302,8 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
             fault: Optional[KernelFault] = None,
             ext_j: bool = False, ext_k: bool = False,
             n_global: Optional[int] = None,
-            p_global: Optional[int] = None) -> jax.Array:
+            p_global: Optional[int] = None,
+            i_halo: Optional[tuple] = None) -> jax.Array:
     """Wire a fused volumetric kernel: ``a4`` is ``(B, M, N, P)``.
 
     ``path="stream"`` (default) walks the i-blocks in order and carries the
@@ -299,6 +327,12 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
     ``n_global``/``p_global`` (the global N/P).  External j is
     incompatible with j-tiling (the tile walk would re-wrap the exchanged
     columns), so ``bj`` must be ``None``.
+
+    ``i_halo`` passes a shard's exchanged i ghost slabs to the stream
+    kernel (``path="stream"`` only) as operands (see
+    :func:`_call_3d_stream`); ``a4`` then holds
+    the resident planes only and ``geom[0]`` is the global row of its
+    first.
     """
     b, m, n, p = a4.shape
     if (ext_j or ext_k) and bj is not None:
@@ -311,7 +345,7 @@ def call_3d(a4: jax.Array, wf: jax.Array, geom: jax.Array, plan: StencilPlan,
         return _call_3d_stream(a4, wf, geom, plan, bi, bj, sweeps, interpret,
                                external_i_halo, fault, ext_j=ext_j,
                                ext_k=ext_k, n_global=n_global,
-                               p_global=p_global)
+                               p_global=p_global, i_halo=i_halo)
     if path != "replicate":
         raise ValueError(f"unknown path {path!r}; expected 'stream' or "
                          f"'replicate'")

@@ -19,7 +19,11 @@ from HBM exactly once and carries the halo window in VMEM scratch
 j-tiled blocking engages when the local N x P slab exceeds the VMEM
 budget); the kernel's geometry operand (global row offset, global M, and
 the j/k offsets where those axes are sharded) keeps the interior/boundary
-masking correct across shard seams.
+masking correct across shard seams.  The stream kernel takes the two
+exchanged i ghost slabs as operands and sweeps the resident planes, so
+the shard's field is neither copied into an i-extended slab nor its
+output cropped on i; the j/k ghosts, and the i ghosts of the other
+kernels, are concatenated onto the slab.
 
 The compiled shard_map program is memoized in a small bounded cache keyed on
 the mesh's *device ids + topology + axis names* (plus the execution
@@ -116,7 +120,8 @@ _AXIS_LABEL = ("i", "j", "k")
 def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
                      bj: Optional[int], sweeps: int, interpret: bool,
                      halos, locs, nsh, gshape, part, path: str = "stream",
-                     mode: str = "fused", overlap: str = "off"):
+                     mode: str = "fused", overlap: str = "off",
+                     i_halo: Optional[str] = None):
     """Build (and cache) the jitted shard_map program for an (pi, pj, pk)
     process grid.
 
@@ -131,9 +136,15 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
     only ones the interior compute has to hide.
 
     ``overlap="off"`` (the serialized, bit-exact escape hatch) runs one
-    kernel call on the fully extended slab.  ``overlap="on"`` splits the
-    i-axis work: the two i ghost-slab ppermutes are issued with no
-    consumer between them and the interior :func:`~.ops.call_3d` (which
+    kernel call.  With ``i_halo="operands"`` the stream kernel takes the
+    two exchanged i ghost slabs as operands and sweeps the resident planes
+    (no slab extended on i, no crop on i); with ``"concat"`` the slabs are
+    concatenated onto the slab and the kernel's output is cropped back to
+    the resident planes (the wavefront and replicate kernels, the overlap
+    executor, and a halo deeper than the kernel's); ``None`` where no i
+    planes are exchanged.  ``overlap="on"`` splits the i-axis work: the
+    two i ghost-slab ppermutes are issued with no consumer between them
+    and the interior :func:`~.ops.call_3d` (which
     reads only resident planes and discards its ``h`` edge rows), leaving
     XLA free to run the collectives concurrently with the interior sweep;
     the two ``h``-deep boundary strips are then swept from the arrived
@@ -141,7 +152,7 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
     each) and concatenated around the cropped interior."""
     key = ("grid", cplan, _mesh_key(mesh), tuple(names), bi, bj, sweeps,
            interpret, tuple(halos), tuple(locs), tuple(nsh), tuple(gshape),
-           part, path, mode, overlap, _HALO_FAULT_VERSION[0])
+           part, path, mode, overlap, i_halo, _HALO_FAULT_VERSION[0])
     fn = _SHARDED_CACHE.get(key)
     if fn is not None:
         _SHARDED_CACHE.move_to_end(key)
@@ -188,14 +199,25 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
         jk = [offs[1] - hs[1], offs[2] - hs[2]] if ext_j or ext_k else []
         return jnp.stack([i_row, jnp.int32(m_gl), *jk]).astype(jnp.int32)
 
+    operands = i_halo == "operands"
+
     def local_serial(a_loc: jax.Array, wf_: jax.Array) -> jax.Array:
         offs = _offsets()
         ext, wx = a_loc, wf_
-        for d in (1, 2, 0):         # j, then k, then i: transitive corners
+        for d in (1, 2) if operands else (1, 2, 0):
+            # j, then k, then i: transitive corners
             if names[d] is not None and hs[d] > 0:
                 ext = _exchange(ext, d)
                 if var:
                     wx = _exchange(wx, d)
+        if operands:
+            # i last, as operands: the slabs carry the j/k ghost columns
+            slabs = _pperm_pair(ext, 0) + (_pperm_pair(wx, 0) if var
+                                           else ())
+            out = call_3d(ext, wx, _geom(offs[0], offs), cplan, bi, bj,
+                          sweeps, interpret, path, ext_j=ext_j, ext_k=ext_k,
+                          n_global=n_gl, p_global=p_gl, i_halo=slabs)
+            return out[:, :, hs[1]:hs[1] + locs[1], hs[2]:hs[2] + locs[2]]
         geom = _geom(offs[0] - hs[0], offs)
         if mode == "wavefront":
             out = call_3d_wavefront(ext, wx, geom, cplan, bi, sweeps,
@@ -255,7 +277,8 @@ def _grid_sharded_fn(cplan: StencilPlan, mesh: Mesh, names, bi: int,
     w_spec = part if var else P(None)
     return _cache_program(key, jax.shard_map(
         _traced(local_fn, cplan, mode=mode, path=path, block_i=bi,
-                block_j=bj, plan=cplan.kind, sweeps=sweeps, overlap=overlap),
+                block_j=bj, plan=cplan.kind, sweeps=sweeps, overlap=overlap,
+                i_halo=i_halo),
         mesh=mesh, in_specs=(part, w_spec), out_specs=part,
         check_vma=False))
 
@@ -317,14 +340,14 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
     use_overlap = (overlap == "on" and names[0] is not None and hs[0] > 0
                    and m_l >= 2 * hs[0] and block_j is None
                    and mode != "wavefront")
-    # overlap tunes for the interior call (resident m_l planes); serialized
-    # tunes for the one fully extended slab
-    m_tune = m_l if use_overlap else m_ext
-    if block_i is not None and m_tune % block_i != 0:
-        raise ValueError(
-            f"sharded block_i={block_i} must divide the local i extent "
-            f"{m_tune} ({'resident, overlap interior' if use_overlap else 'halo-extended'}); "
-            f"omit block_i to let the cost model choose")
+    # The fused stream kernel takes the exchanged i planes as operands
+    # when they are exactly its halo: it then sweeps the resident planes.
+    operands = (names[0] is not None and hs[0] > 0 and not use_overlap
+                and hs[0] == spec.radius[0] * sweeps * apps
+                and mode != "wavefront" and path != "replicate")
+    # overlap's interior call and the operand path tune for the resident
+    # m_l planes; the others for the one i-extended slab
+    m_tune = m_l if use_overlap or operands else m_ext
     bi, bj, rpath = block_i, block_j, path
     run_mode = mode
     if run_mode == "auto":
@@ -341,6 +364,13 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
             run_mode = "wavefront" if sel.mode == "wavefront" else "fused"
             if run_mode == "wavefront" and names[0] is None and per[0]:
                 run_mode = "fused"  # see the explicit-mode raise above
+            if run_mode == "wavefront":
+                operands, m_tune = False, m_ext
+    if block_i is not None and m_tune % block_i != 0:
+        raise ValueError(
+            f"sharded block_i={block_i} must divide the local i extent "
+            f"{m_tune} ({'resident' if m_tune == m_l else 'halo-extended'}); "
+            f"omit block_i to let the cost model choose")
     if run_mode == "wavefront":
         if bj is not None:
             raise ValueError(f"{spec.name}: the wavefront mode is untiled "
@@ -355,6 +385,12 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
                                              a.dtype.itemsize, sweeps=sweeps,
                                              plan=cplan, block_j=bj,
                                              path=path)
+        if operands and rpath == "replicate":
+            # no stream blocking fits: the replicated kernel, on the slab
+            operands = False
+            rpath, bi, bj_auto = autotune_engine(
+                m_ext, n_ext, p_ext, a.dtype.itemsize, sweeps=sweeps,
+                plan=cplan, block_j=bj, path=rpath)
         bj = bj if bj is not None else bj_auto
         if names[1] is not None or names[2] is not None:
             bj = None               # external j/k ghosts: tiling disallowed
@@ -363,12 +399,15 @@ def _select_grid(span, a: jax.Array, w: jax.Array, spec: StencilSpec,
     if use_overlap:
         bj = None                   # the overlap executor's calls are untiled
     run_overlap = "on" if use_overlap else "off"
+    i_halo = None
+    if names[0] is not None and hs[0] > 0:
+        i_halo = "operands" if operands else "concat"
     annotate(span, mode=run_mode, path=rpath, block_i=bi, block_j=bj,
              overlap=run_overlap, halo=hs, local=grid_plan.local)
     fn = _grid_sharded_fn(cplan, mesh, names, bi, bj, sweeps, interpret,
                           grid_plan.halo, grid_plan.local,
                           grid_plan.n_shards, (m, n, p), grid_plan.spec,
-                          rpath, run_mode, run_overlap)
+                          rpath, run_mode, run_overlap, i_halo)
     return functools.partial(_launch, fn, a, wf)
 
 
@@ -390,14 +429,15 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
     ``axes=(axis, None, None)``.  Returns the same value as the
     single-device path; falls back to it when the planner declines to shard.
     ``path`` selects the per-shard data-movement strategy exactly as in
-    ``stencil_apply`` -- ``"auto"`` streams the halo-extended local slab
-    (each local plane fetched once), ``"replicate"`` re-fetches the halo
+    ``stencil_apply`` -- ``"auto"`` streams the local planes with the
+    exchanged i planes as kernel operands (each local plane fetched
+    once), ``"replicate"`` re-fetches the halo
     neighbours per block (parity escape hatch).  ``mode`` selects the
     per-shard time integration: ``"fused"`` (default) runs one fused
     ``sweeps=s`` kernel per shard; ``"wavefront"`` runs the
     temporal-wavefront pipeline (:func:`~.ops.call_3d_wavefront`) per
     shard; ``"auto"`` races them on the sweeps-aware roofline over the
-    halo-extended local slab.  Either way ``s`` sweeps cost *one*
+    local planes.  Either way ``s`` sweeps cost *one*
     ``radius * sweep_apps * s``-deep ppermute round -- shard-edge strips
     are redundantly recomputed from the deep halo instead of re-exchanged
     per sweep.  ``bc`` overrides the
@@ -406,9 +446,13 @@ def stencil_sharded(a: jax.Array, w: jax.Array,
     0 and shard ``n-1``) while dirichlet/neumann ghosts materialize only on
     the boundary shards via the kernel's global-geometry fill.
 
-    Note: the kernel runs per shard on the halo-extended local slab, so an
-    explicit ``block_i`` must divide ``M / n_shards + 2 * sweeps`` (not M);
-    it is ignored when the planner falls back to the unsharded path.  Omit
+    Note: an explicit ``block_i`` blocks each shard's i extent, not M.  The
+    fused stream kernel takes the exchanged i planes as operands and
+    sweeps the resident ``M / n_shards`` planes, which ``block_i`` must
+    then divide; the wavefront and replicated kernels (and a ``shard_plan``
+    halo deeper than the kernel's) sweep the slab extended by ``radius *
+    sweep_apps * sweeps`` planes a side, which it must divide there.  It
+    is ignored when the planner falls back to the unsharded path.  Omit
     it to let the plan-aware cost model choose in every configuration
     (including a j-tile width when the local slab overflows VMEM).
 

@@ -183,23 +183,37 @@ def test_grid_2x2x2_parity_subprocess():
 # in-process 8-device matrix (the dedicated multidevice CI leg)
 # ---------------------------------------------------------------------------
 
+# name -> (mesh shape, axes, j-tiled): the (2, 2, 2) grid, and i-chains
+# on which the fused stream kernel takes the exchanged i planes as
+# operands, untiled and j-tiled; on two shards every shard is an edge
+# shard (a chain's edge shard receives one zero slab)
+GRIDS = {"2x2x2": ((2, 2, 2), ("x", "y", "z"), False),
+         "chain4": ((4,), ("x", None, None), False),
+         "chain4-jtiled": ((4,), ("x", None, None), True),
+         "chain2": ((2,), ("x", None, None), False)}
+
+
 @multidevice
+@pytest.mark.parametrize("grid", sorted(GRIDS))
 @pytest.mark.parametrize("bc", [None, "periodic"])
 @pytest.mark.parametrize("name,shape", [("stencil27", (8, 8, 16)),
                                         ("star13", (16, 16, 16))])
 @pytest.mark.parametrize("path", ["stream", "replicate"])
 @pytest.mark.parametrize("overlap", ["off", "on"])
-def test_grid_3d_bitexact_vs_oracle(bc, name, shape, path, overlap):
-    """Corner/edge ghost correctness: a (2,2,2)-sharded run is bit-exact vs
-    the single-device oracle on integer data -- BC x radius {1 (the
-    diagonal-heavy stencil27, where wrong corners change the answer),
-    2 (star13)} x path x overlap."""
+def test_grid_3d_bitexact_vs_oracle(bc, name, shape, path, overlap, grid):
+    """Corner/edge ghost correctness: a sharded run is bit-exact vs the
+    single-device oracle on integer data -- BC (chain or ring) x radius
+    {1 (the diagonal-heavy stencil27, where wrong corners change the
+    answer), 2 (star13)} x path x overlap x process grid."""
+    mesh_shape, axes, tiled = GRIDS[grid]
     a = _field(shape, seed=7)
     w = _weights(name, seed=8)
-    mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"))
+    mesh = jax.make_mesh(mesh_shape, [ax for ax in axes if ax],
+                         devices=jax.devices()[:np.prod(mesh_shape)])
     ref = stencil_apply(a, w, name, bc=bc, sweeps=2)
-    got = stencil_sharded(a, w, name, mesh=mesh, axes=("x", "y", "z"),
-                          bc=bc, sweeps=2, path=path, overlap=overlap)
+    got = stencil_sharded(a, w, name, mesh=mesh, axes=axes, bc=bc,
+                          sweeps=2, path=path, overlap=overlap,
+                          block_j=shape[1] // 2 if tiled else None)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 

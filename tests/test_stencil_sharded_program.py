@@ -76,6 +76,87 @@ def compiles():
     return json.loads(proc.stdout.splitlines()[-1])
 
 
+# name -> (field shape, bc, block_j) on the (4,) chain: 11 resident
+# planes a shard, 13 once extended by the 1-plane halo a side
+SLAB_CASES = {"chain-clamp": ((44, 8, 128), "clamp", None),
+              "chain-periodic": ((44, 8, 128), "periodic", None),
+              "chain-clamp-jtiled": ((44, 32, 128), "clamp", 16)}
+
+SLAB_SCRIPT = textwrap.dedent("""
+    import json
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.extend import core
+    from repro.kernels import stencil_sharded
+
+    def eqns(jaxpr):
+        # every equation of the program, the kernels' own bodies left out
+        for e in jaxpr.eqns:
+            yield e
+            if e.primitive.name == "pallas_call":
+                continue
+            for v in e.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    if isinstance(sub, core.ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, core.Jaxpr):
+                        yield from eqns(sub)
+
+    mesh = jax.make_mesh((4,), ("i",))
+    w = np.asarray([[[26, -1], [-1, -1]], [[-1, -1], [-1, -1]]], np.float32)
+    out = {{}}
+    for name, (shape, bc, bj) in {cases!r}.items():
+        u = jnp.ones(shape, jnp.float32)
+        def call(u, w):
+            return stencil_sharded(u, w, "stencil27", mesh=mesh, axis="i",
+                                   bc=bc, block_j=bj)
+        jaxpr = jax.make_jaxpr(call)(u, w).jaxpr
+        rows = []
+        for e in eqns(jaxpr):
+            rows.append([e.primitive.name,
+                         [list(getattr(v.aval, "shape", ()))
+                          for v in e.invars],
+                         [list(v.aval.shape) for v in e.outvars]])
+        out[name] = rows
+    print(json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def shard_programs():
+    """``{case: [[primitive, in shapes, out shapes]]}``: the equations of
+    the sharded call's traced program, the kernels' bodies left out (their
+    working strips are ``2h`` planes deeper than their blocks)."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": str(ROOT / "src"),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    proc = subprocess.run(
+        [sys.executable, "-c", SLAB_SCRIPT.format(cases=SLAB_CASES)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_no_i_extended_slab_around_the_kernel(shard_programs, case):
+    """The stream kernel takes the exchanged i planes as operands: no
+    concatenation or pad makes a slab of ``m_l + 2h`` planes, no slice
+    crops such a kernel output, and the kernel writes the local shard."""
+    (m, n, p), _, _ = SLAB_CASES[case]
+    m_l, h = m // 4, 1
+    r = shard_programs[case]
+    kernels = [(ins, outs) for name, ins, outs in r if name == "pallas_call"]
+    assert len(kernels) == 1, r
+    ins, outs = kernels[0]
+    assert outs == [[1, m_l, n, p]], kernels
+    # the two exchanged slabs ride in as operands
+    assert [1, h, n, p] in ins, kernels
+    for name, ins, outs in r:
+        if name in ("concatenate", "pad"):
+            assert all(o[-3:] != [m_l + 2 * h, n, p] for o in outs), outs
+        if name in ("slice", "dynamic_slice"):
+            assert ins[0][-3:] != [m_l + 2 * h, n, p], ins
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_no_eager_reshape_of_the_field(compiles, case):
     shape = CASES[case][0]

@@ -5,13 +5,16 @@ with the engine's own plan, path and blocks on a field sharded on i, and
 the benchmark's ``correct`` compares its output with
 ``bench/references/stencil27.py``, plain ``jnp`` written apart from the
 engine.  These tests make the same comparison on four virtual CPU devices
-(Pallas interpreted), on seeded uniform f32 data, under ``clamp`` and
-``periodic``, at shapes that take the two geometries the deployment takes
-on the chip: a halo-extended slab the engine blocks with an odd
-``block_i`` (its 1026 planes a chip get 3), and a j-tiled stream call
-whose j-halo narrowing engages.  The geometry is read from the program's
-own ``stencil.select`` and ``stencil.trace`` spans.  Every case runs in one child process, since this
-one must keep seeing one device.
+(Pallas interpreted), on seeded uniform f32 data, under ``clamp`` (a
+chain) and ``periodic`` (a ring), at shapes that take the geometry the
+deployment takes on the chip: the stream kernel takes the two exchanged
+i planes as operands and sweeps the resident planes alone
+(``i_halo="operands"``), untiled with an odd ``block_i`` and j-tiled with
+the j-halo narrowing engaged, on four shards and on two, where every
+shard is an edge shard and on the chain receives one zero slab.  The
+geometry is read from the program's own ``stencil.select`` and
+``stencil.trace`` spans.  Every case runs in one child process, since
+this one must keep seeing one device.
 """
 
 from __future__ import annotations
@@ -35,9 +38,12 @@ ROOT = Path(__file__).resolve().parents[1]
 # reads about 4e-3, so it cannot pass.
 TOL = 1e-6
 
-# name -> (global shape, block_j passed, whether halo_rows must engage)
-CASES = {"odd_bi": ((28, 16, 128), None, False),
-         "jtiled": ((36, 32, 128), 16, True)}
+# name -> (global shape, block_j passed, whether halo_rows must engage,
+# shards on i)
+CASES = {"odd_bi": ((28, 16, 128), None, False, 4),
+         "jtiled": ((36, 32, 128), 16, True, 4),
+         "two_shards": ((16, 16, 128), None, False, 2),
+         "two_shards_jtiled": ((18, 32, 128), 16, True, 2)}
 BCS = ("clamp", "periodic")
 
 SCRIPT = textwrap.dedent("""
@@ -51,11 +57,12 @@ SCRIPT = textwrap.dedent("""
     from repro.kernels.stencil_engine import common
 
     assert len(jax.devices()) == 4, jax.devices()
-    mesh = jax.make_mesh((4,), ("i",))
     w = [[[26, -1], [-1, -1]], [[-1, -1], [-1, -1]]]
     wa = np.asarray(w, np.float32)
     out = {{}}
-    for name, (shape, bj, _) in {cases!r}.items():
+    for name, (shape, bj, _, shards) in {cases!r}.items():
+        mesh = jax.make_mesh((shards,), ("i",),
+                             devices=jax.devices()[:shards])
         seed = sum(map(ord, name))
         key = jax.random.key(seed)
         u = jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0)
@@ -109,15 +116,19 @@ def test_sharded_matches_plain_reference(readings, case, bc):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_sharded_geometry_is_the_cells(readings, case):
-    """The odd block on the extended slab, and the j-halo narrowing, are
-    what the program ran, as its spans record it: the cases test the
-    geometry they name."""
-    shape, bj, narrowed = CASES[case]
+    """The i planes as kernel operands, the block on the resident planes,
+    the odd block and the j-halo narrowing are what the program ran, as
+    its spans record it: the cases test the geometry they name."""
+    shape, bj, narrowed, shards = CASES[case]
     r = readings[f"{case}-clamp"]
     sel, body = r["select"], r["trace"]
+    m_l = shape[0] // shards
     assert sel["halo"] == str((1, 0, 0)), r
-    assert sel["local"] == str((shape[0] // 4, *shape[1:])), r
-    assert sel["block_i"] % 2 == 1 and body["block_i"] == sel["block_i"], r
+    assert sel["local"] == str((m_l, *shape[1:])), r
+    assert body["i_halo"] == "operands", r
+    assert m_l % sel["block_i"] == 0 and body["block_i"] == sel["block_i"], r
+    if case == "odd_bi":
+        assert sel["block_i"] % 2 == 1, r
     if narrowed:
         assert sel["block_j"] == body["block_j"] == bj, r
         assert body["halo_rows"] == 8, r

@@ -114,6 +114,7 @@ def test_entry_spans_nest_and_trace_once(tmp_path, entry, call):
               for o in (first, second)]
     assert len(traces[0]) == 1 and traces[1] == []
     assert {"path", "block_i", "plan", "sweeps"} <= set(traces[0][0][3])
+    assert "i_halo" not in traces[0][0][3]      # one device: no exchange
 
 
 def test_driver_select_carries_the_autotuned_choice(tmp_path):
@@ -206,6 +207,8 @@ def test_sharded_spans_on_four_devices(tmp_path):
     # halo_rows only on a j-tiled stream call
     tiled = traces[0][3]["path"] == "stream" and "block_j" in traces[0][3]
     assert ("halo_rows" in traces[0][3]) == tiled
+    # the stream chain's kernel takes the exchanged i planes as operands
+    assert traces[0][3]["i_halo"] == "operands"
 
 
 SHARDED_CASE = textwrap.dedent("""
@@ -228,18 +231,28 @@ SHARDED_CASE = textwrap.dedent("""
     # the i chain, j-tiled by a passed block_j: the narrowing engages
     ((4,), ("i",), (32, 32, 128), dict(axis="i", block_j=16),
      dict(halo=str((1, 0, 0)), local=str((8, 32, 128))),
-     dict(block_j=16, halo_rows=8)),
+     dict(block_j=16, halo_rows=8, i_halo="operands")),
     # a 2x2x1 process grid: per-axis (i, j, k) halo and local extents
     ((2, 2), ("i", "j"), (16, 16, 128), dict(axes=("i", "j", None)),
-     dict(halo=str((1, 1, 0)), local=str((8, 8, 128))), dict()),
-], ids=["chain-jtiled", "grid-2x2x1"])
+     dict(halo=str((1, 1, 0)), local=str((8, 8, 128))),
+     dict(i_halo="operands")),
+    # the wavefront kernel sweeps the slab the exchanged planes extend
+    ((4,), ("i",), (32, 16, 128), dict(axis="i", mode="wavefront"),
+     dict(halo=str((1, 0, 0)), mode="wavefront"),
+     dict(mode="wavefront", i_halo="concat")),
+    # i whole: no i planes exchanged, no argument
+    ((4,), ("j",), (8, 32, 128), dict(axes=(None, "j", None)),
+     dict(halo=str((0, 1, 0))), dict(i_halo=None)),
+], ids=["chain-jtiled", "grid-2x2x1", "chain-wavefront", "j-only"])
 def test_sharded_spans_carry_the_shard_geometry(tmp_path, mesh_shape,
                                                 mesh_axes, shape, kwargs,
                                                 select, trace):
     """The sharded entry's ``stencil.select`` carries the exchange depth
     and the local extents; the shard body's ``stencil.trace`` carries
-    ``halo_rows`` on a j-tiled stream call and not otherwise.  Span
-    arguments that are tuples are recorded as their text."""
+    ``halo_rows`` on a j-tiled stream call and not otherwise, and
+    ``i_halo``, how the exchanged i planes reach the kernel, where i is
+    sharded.  Span arguments that are tuples are recorded as their
+    text."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(SRC),
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
     script = SHARDED_CASE.format(tests=str(Path(__file__).parent),
